@@ -35,10 +35,11 @@ import sys
 import time
 from typing import List
 
+import jax
 import numpy as np
 
 from benchmarks.common import Row, bench_graphs, row
-from repro.compat import jax_version_str, resolve_interpret
+from repro.compat import resolve_interpret
 from repro.dynamic import random_mutations
 from repro.index import BuildPlan, CHLIndex, build
 
@@ -142,7 +143,7 @@ def run(quick: bool = False) -> List[Row]:
 
     BENCH_JSON.write_text(json.dumps({
         "generated_by": "benchmarks/dynamic_bench.py",
-        "jax": jax_version_str(),
+        "jax": jax.__version__,
         "pallas_backend": mode,
         "quick": quick,
         "road_small_batch_speedup": road_speedup_small,

@@ -56,10 +56,9 @@ def run() -> List[Row]:
                            timeout=1200)
         line = [l for l in p.stdout.splitlines()
                 if l.startswith("RESULT")]
-        if not line:
-            out.append(row(f"fig8/q={q}/FAILED", 0.0,
-                           p.stderr[-200:]))
-            continue
+        if p.returncode != 0 or not line:
+            raise RuntimeError(f"fig8 child (q={q}) failed with exit "
+                               f"code {p.returncode}:\n{p.stderr[-2000:]}")
         res = json.loads(line[0][len("RESULT"):])
         for algo, st in res.items():
             out.append(row(

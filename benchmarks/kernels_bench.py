@@ -15,10 +15,11 @@ from typing import List
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from benchmarks.common import Row, row, timed
-from repro.compat import jax_version_str, resolve_interpret
+from repro.compat import resolve_interpret
 from repro.kernels.ell_relax import ell_sweep
 from repro.kernels.label_query import label_query_padded, label_query_ref
 from repro.kernels.minplus import minplus_padded, minplus_ref
@@ -260,7 +261,7 @@ def _run_label_store(idx, g, rng):
 def _write_json(rows: List[Row], mode: str, label_bytes=None) -> None:
     BENCH_JSON.write_text(json.dumps({
         "generated_by": "benchmarks/kernels_bench.py",
-        "jax": jax_version_str(),
+        "jax": jax.__version__,
         "pallas_backend": mode,
         "label_bytes": label_bytes or {},
         "rows": rows,

@@ -33,10 +33,11 @@ import tempfile
 import time
 from typing import List
 
+import jax
 import numpy as np
 
 from benchmarks.common import Row, bench_graphs, row
-from repro.compat import jax_version_str, resolve_interpret
+from repro.compat import resolve_interpret
 from repro.index import BuildPlan, CHLIndex, build
 from repro.serve import make_answer_fn, poisson_open_loop, zipf_pairs
 
@@ -184,7 +185,7 @@ def run(quick: bool = False) -> List[Row]:
 
     BENCH_JSON.write_text(json.dumps({
         "generated_by": "benchmarks/serving_bench.py",
-        "jax": jax_version_str(),
+        "jax": jax.__version__,
         "pallas_backend": mode,
         "quick": quick,
         "skipped_nan_rows": skipped,

@@ -77,8 +77,9 @@ def run() -> List[Row]:
                        capture_output=True, text=True, env=env,
                        timeout=2700)
     line = [l for l in p.stdout.splitlines() if l.startswith("RESULT")]
-    if not line:
-        return [row("table4/FAILED", 0.0, p.stderr[-200:])]
+    if p.returncode != 0 or not line:
+        raise RuntimeError(f"table4 child failed with exit code "
+                           f"{p.returncode}:\n{p.stderr[-2000:]}")
     res = json.loads(line[0][len("RESULT"):])
     Q = res["Q"]
     out: List[Row] = []

@@ -1,40 +1,29 @@
 """``repro.compat`` — the only place this repo touches unstable JAX API.
 
-JAX's public surface for multi-device programming and Pallas moves
-between minor releases: ``shard_map`` migrated from
-``jax.experimental.shard_map`` to top-level ``jax.shard_map`` (and its
-replication-check kwarg was renamed ``check_rep`` → ``check_vma``),
-``jax.make_mesh`` grew an ``axis_types=`` kwarg backed by a new
-``jax.sharding.AxisType`` enum, Pallas renamed
-``pltpu.TPUCompilerParams`` → ``pltpu.CompilerParams``, and the set of
-XLA flags the bundled runtime accepts changes (unknown flags in
-``XLA_FLAGS`` *abort the process* at backend init).
+The installed stack is jax/jaxlib 0.9.0: top-level ``jax.shard_map``
+with ``check_vma``, ``jax.make_mesh(axis_types=)`` and
+``pltpu.CompilerParams``. XLA aborts the process at backend init on an
+unknown flag in ``XLA_FLAGS``, so flags are probed in a subprocess
+first (``repro.compat.xla``).
 
-Everything else in the repo goes through the resolvers here; nothing
+Everything else in the repo goes through the helpers here; nothing
 outside ``repro.compat`` may import ``shard_map``, construct TPU
 compiler params, reference ``AxisType``, or write raw ``XLA_FLAGS``
 (enforced by ``tests/test_compat.py::test_no_direct_unstable_imports``).
-
-Supported range: jax >= 0.4.37 (older spellings) through current
-releases (newer spellings) — each resolver probes the installed
-module/signature rather than pinning a version table.
 """
 
-from repro.compat.version import JAX_VERSION, jax_version_str
-from repro.compat.shardmap import replication_kwarg, resolve_shard_map, shard_map
-from repro.compat.meshes import (axis_types_supported, make_mesh,
-                                 mesh_axis_kwargs)
-from repro.compat.pallas import (compiler_params_cls, pallas_call,
-                                 prefetch_scalar_grid_spec,
-                                 resolve_interpret, tpu_compiler_params)
+from repro.compat.cache import enable_compile_cache
+from repro.compat.shardmap import shard_map
+from repro.compat.meshes import make_mesh
+from repro.compat.pallas import (pallas_call, prefetch_scalar_grid_spec,
+                                 resolve_interpret, target_platform)
 from repro.compat.xla import (COLLECTIVE_TIMEOUT_FLAGS, apply_xla_flags,
                               host_device_flags, set_host_device_count,
                               supported_xla_flags, xla_flags)
 
 
 def capabilities() -> dict:
-    """One-stop report of what the installed JAX supports — for logs
-    and bug reports.
+    """What the installed JAX runs on — for logs and bug reports.
 
     Best-effort by design: a diagnostics helper must not raise on the
     very misconfigurations it exists to surface. Note that reading the
@@ -55,13 +44,7 @@ def capabilities() -> dict:
     except Exception as e:                       # noqa: BLE001
         interpret = f"error: {e}"
     return {
-        "jax_version": jax_version_str(),
-        "shard_map_location": ("jax" if hasattr(jax, "shard_map")
-                               else "jax.experimental.shard_map"),
-        "replication_kwarg": replication_kwarg(resolve_shard_map()),
-        "mesh_axis_types": axis_types_supported(),
-        "tpu_compiler_params": getattr(compiler_params_cls(), "__name__",
-                                       None),
+        "jax_version": jax.__version__,
         "default_backend": backend,
         "pallas_backend_env": os.environ.get(
             "REPRO_PALLAS_BACKEND", None),
@@ -70,11 +53,9 @@ def capabilities() -> dict:
 
 
 __all__ = [
-    "JAX_VERSION", "jax_version_str",
-    "resolve_shard_map", "replication_kwarg", "shard_map",
-    "make_mesh", "mesh_axis_kwargs", "axis_types_supported",
-    "pallas_call", "resolve_interpret", "tpu_compiler_params",
-    "compiler_params_cls", "prefetch_scalar_grid_spec",
+    "enable_compile_cache", "shard_map", "make_mesh",
+    "pallas_call", "resolve_interpret", "target_platform",
+    "prefetch_scalar_grid_spec",
     "COLLECTIVE_TIMEOUT_FLAGS", "supported_xla_flags", "xla_flags",
     "apply_xla_flags", "host_device_flags", "set_host_device_count",
     "capabilities",

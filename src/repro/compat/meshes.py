@@ -1,72 +1,19 @@
-"""``jax.make_mesh`` signature drift: the ``axis_types=`` kwarg.
-
-Newer JAX has ``jax.sharding.AxisType`` and ``jax.make_mesh(...,
-axis_types=(AxisType.Auto, ...))``; 0.4.x has neither. Mesh builders
-in this repo call ``compat.make_mesh`` with axis types named as
-strings (``"auto"`` / ``"explicit"`` / ``"manual"``); the translator
-resolves them against the installed enum or silently drops the kwarg
-when the installed jax predates it (its behavior then matches
-``Auto`` everywhere, which is what every call site wants).
-"""
+"""``jax.make_mesh`` with every axis typed ``Auto``."""
 
 from __future__ import annotations
 
-import inspect
-from typing import Any, Optional, Sequence
+from typing import Sequence
 
 import jax
 
 
-def _axis_type_enum(sharding_module: Any = None):
-    mod = jax.sharding if sharding_module is None else sharding_module
-    return getattr(mod, "AxisType", None)
-
-
-def axis_types_supported() -> bool:
-    return _axis_type_enum() is not None and _accepts_axis_types(jax.make_mesh)
-
-
-def _accepts_axis_types(fn) -> bool:
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):
-        return False
-    if "axis_types" in params:
-        return True
-    return any(p.kind is inspect.Parameter.VAR_KEYWORD
-               for p in params.values())
-
-
-def mesh_axis_kwargs(n_axes: int,
-                     axis_types: Optional[Sequence[str]] = None,
-                     make_mesh_fn=None, axis_type_cls=None) -> dict:
-    """The ``axis_types=`` kwargs dict for ``make_mesh`` — empty when
-    the installed jax has no such concept.
-
-    ``axis_types``: per-axis names among ``auto`` / ``explicit`` /
-    ``manual`` (case-insensitive); default all-``auto``.
-    """
-    fn = jax.make_mesh if make_mesh_fn is None else make_mesh_fn
-    cls = _axis_type_enum() if axis_type_cls is None else axis_type_cls
-    if cls is None or not _accepts_axis_types(fn):
-        return {}
-    names = tuple(axis_types) if axis_types is not None else ("auto",) * n_axes
-    if len(names) != n_axes:
-        raise ValueError(f"{len(names)} axis_types for {n_axes} axes")
-    resolved = []
-    for name in names:
-        member = getattr(cls, name.capitalize(), None)
-        if member is None:
-            raise ValueError(f"unknown axis type {name!r}; installed "
-                             f"AxisType has {[m for m in dir(cls) if not m.startswith('_')]}")
-        resolved.append(member)
-    return {"axis_types": tuple(resolved)}
-
-
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
-              axis_types: Optional[Sequence[str]] = None, devices=None):
-    """Version-portable ``jax.make_mesh`` (axis types as strings)."""
-    kwargs = mesh_axis_kwargs(len(tuple(axis_names)), axis_types)
+              devices=None):
+    """A mesh whose axes are all ``AxisType.Auto`` (the compiler picks
+    the sharding of intermediates, which every call site relies on).
+    ``devices`` may be described devices (a compile-only topology)."""
+    names = tuple(axis_names)
+    kwargs = {"axis_types": (jax.sharding.AxisType.Auto,) * len(names)}
     if devices is not None:
         kwargs["devices"] = devices
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    return jax.make_mesh(tuple(axis_shapes), names, **kwargs)

@@ -1,33 +1,30 @@
-"""Pallas backend dispatch + TPU compiler-params drift.
+"""Pallas backend dispatch.
 
-Two jobs:
+``pallas_call(...)`` is the single place that decides *how* a kernel
+runs. Kernels declare what they need (grid/specs/
+``dimension_semantics``); the dispatcher reads the platform and picks
+compiled-TPU vs ``interpret=True`` emulation, overridable with one env
+var::
 
-- ``tpu_compiler_params(...)``: the params class was renamed
-  ``pltpu.TPUCompilerParams`` (jax 0.4.x) → ``pltpu.CompilerParams``
-  (newer). Resolve whichever exists and drop constructor kwargs the
-  installed class doesn't know.
+    REPRO_PALLAS_BACKEND=auto|compiled|interpret   (default: auto)
 
-- ``pallas_call(...)``: single place that decides *how* a kernel runs.
-  Kernels declare what they need (grid/specs/``dimension_semantics``);
-  the dispatcher probes the platform and picks compiled-TPU vs
-  ``interpret=True`` emulation, overridable with one env var::
+``auto`` compiles on TPU and interprets everywhere else. Callers may
+still force a mode programmatically (tests of the compiled path), but
+the default everywhere is ``interpret=None`` → dispatch.
 
-      REPRO_PALLAS_BACKEND=auto|compiled|interpret   (default: auto)
-
-  ``auto`` compiles on TPU and interprets everywhere else. This
-  replaces per-call-site ``interpret=True`` plumbing: callers may
-  still force a mode programmatically (tests of the compiled path),
-  but the default everywhere is ``interpret=None`` → dispatch.
+The platform is read through :func:`target_platform`, never straight from
+``jax.default_backend()`` at the call sites, so a compile-only test
+for a described (not attached) TPU can steer it.
 """
 
 from __future__ import annotations
 
-import inspect
 import os
-from typing import Any, Mapping, Optional, Sequence
+from typing import Mapping, Optional, Sequence
 
 import jax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BACKEND_ENV_VAR = "REPRO_PALLAS_BACKEND"
 
@@ -35,37 +32,9 @@ _TRUTHY = ("interpret", "1", "true", "yes")
 _FALSY = ("compiled", "tpu", "0", "false", "no")
 
 
-def compiler_params_cls(pltpu_module: Any = None):
-    """The installed TPU compiler-params class, or None if the Pallas
-    TPU backend exposes neither spelling."""
-    if pltpu_module is None:
-        try:
-            from jax.experimental.pallas import tpu as pltpu
-        except ImportError:                              # pragma: no cover
-            return None
-        pltpu_module = pltpu
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu_module, name, None)
-        if cls is not None:
-            return cls
-    return None
-
-
-def tpu_compiler_params(*, pltpu_module: Any = None, **kwargs):
-    """Construct TPU compiler params portably, or return None when the
-    class is unavailable. Kwargs the installed class does not accept
-    are dropped (they are tuning hints, never correctness)."""
-    cls = compiler_params_cls(pltpu_module)
-    if cls is None:
-        return None
-    try:
-        accepted = inspect.signature(cls).parameters
-    except (TypeError, ValueError):
-        return cls(**kwargs)
-    if not any(p.kind is inspect.Parameter.VAR_KEYWORD
-               for p in accepted.values()):
-        kwargs = {k: v for k, v in kwargs.items() if k in accepted}
-    return cls(**kwargs)
+def target_platform() -> str:
+    """The platform Pallas kernels are lowered for."""
+    return jax.default_backend()
 
 
 def resolve_interpret(interpret: Optional[bool] = None, *,
@@ -87,35 +56,24 @@ def resolve_interpret(interpret: Optional[bool] = None, *,
     if mode not in ("", "auto"):
         raise ValueError(f"{BACKEND_ENV_VAR}={mode!r}; expected auto, "
                          "compiled, or interpret")
-    plat = jax.default_backend() if platform is None else platform
+    plat = target_platform() if platform is None else platform
     return plat != "tpu"
 
 
 def prefetch_scalar_grid_spec(*, num_scalar_prefetch: int, grid,
                               in_specs, out_specs):
-    """Resolve the Pallas TPU scalar-prefetch grid spec portably.
+    """The Pallas TPU scalar-prefetch grid spec.
 
     ``PrefetchScalarGridSpec`` marks the first ``num_scalar_prefetch``
     operands as scalar tables available *before* kernel launch: block
     index maps receive them as trailing ref arguments and may compute
     data-dependent block indices from them (the mechanism behind the
-    source-windowed ``ell_relax`` gather). Lives under the Pallas TPU
-    namespace but is honored by the interpreter on every backend, so
-    it resolves here rather than being probed at each call site.
+    source-windowed ``ell_relax`` gather). Honored by the interpreter
+    on every backend.
     """
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except ImportError as e:                         # pragma: no cover
-        raise NotImplementedError(
-            "Pallas TPU module unavailable; scalar-prefetch grid "
-            "specs need jax.experimental.pallas.tpu") from e
-    cls = getattr(pltpu, "PrefetchScalarGridSpec", None)
-    if cls is None:                                  # pragma: no cover
-        raise NotImplementedError(
-            "installed Pallas exposes no PrefetchScalarGridSpec; "
-            "scalar-prefetch driven kernels are unavailable")
-    return cls(num_scalar_prefetch=num_scalar_prefetch, grid=grid,
-               in_specs=in_specs, out_specs=out_specs)
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=num_scalar_prefetch, grid=grid,
+        in_specs=in_specs, out_specs=out_specs)
 
 
 def pallas_call(kernel, *, out_shape,
@@ -133,11 +91,10 @@ def pallas_call(kernel, *, out_shape,
     interp = resolve_interpret(interpret)
     if not interp and dimension_semantics is not None \
             and "compiler_params" not in kwargs:
-        if jax.default_backend() == "tpu":
-            params = tpu_compiler_params(
+        plat = target_platform()
+        if plat == "tpu":
+            kwargs["compiler_params"] = pltpu.CompilerParams(
                 dimension_semantics=tuple(dimension_semantics))
-            if params is not None:
-                kwargs["compiler_params"] = params
         elif "arbitrary" in dimension_semantics:
             # 'arbitrary' promises sequential grid execution along that
             # axis (kernels accumulate into their output block under
@@ -145,11 +102,10 @@ def pallas_call(kernel, *, out_shape,
             # annotation, and running the grid concurrently would race
             # the accumulation — refuse rather than return garbage
             raise NotImplementedError(
-                "compiled Pallas dispatch on backend "
-                f"{jax.default_backend()!r} cannot honor 'arbitrary' "
-                f"dimension semantics {tuple(dimension_semantics)}; "
-                "use the TPU backend or interpret mode "
-                f"({BACKEND_ENV_VAR}=interpret)")
+                f"compiled Pallas dispatch on backend {plat!r} cannot "
+                "honor 'arbitrary' dimension semantics "
+                f"{tuple(dimension_semantics)}; use the TPU backend or "
+                f"interpret mode ({BACKEND_ENV_VAR}=interpret)")
     if grid is not None:
         kwargs["grid"] = grid
     if in_specs is not None:

@@ -1,87 +1,19 @@
-"""``shard_map`` resolution across JAX releases.
+"""``shard_map`` with the replication check under one spelling.
 
-Two axes of drift:
-
-- **location**: new JAX exports top-level ``jax.shard_map``; 0.4.x only
-  has ``jax.experimental.shard_map.shard_map``.
-- **replication-check kwarg**: renamed ``check_rep`` (old) →
-  ``check_vma`` (new). Call sites here say ``check_replication=`` and
-  the translator picks whichever spelling the resolved function
-  accepts (or drops it entirely if neither exists).
+Call sites say ``check_replication=``; this forwards it as jax's
+``check_vma``.
 """
 
 from __future__ import annotations
 
-import inspect
-from typing import Any, Callable, Optional
+from typing import Callable
 
 import jax
 
-_REPLICATION_SPELLINGS = ("check_vma", "check_rep")
-
-# Resolved lazily, cached forever — the installed jax does not change
-# mid-process.
-_IMPL: Optional[Callable] = None
-
-
-def resolve_shard_map(jax_module: Any = None) -> Callable:
-    """Locate the shard_map callable for ``jax_module``.
-
-    Preference order: top-level ``.shard_map`` (the stable home), then
-    ``.experimental.shard_map.shard_map`` (the 0.4.x home). Pass a
-    stand-in module object in tests to exercise either path.
-    """
-    if jax_module is not None:
-        fn = getattr(jax_module, "shard_map", None)
-        if fn is None:
-            exp = getattr(jax_module, "experimental", None)
-            sub = getattr(exp, "shard_map", None) if exp is not None else None
-            fn = getattr(sub, "shard_map", None) if sub is not None else None
-        if fn is None:
-            raise AttributeError(
-                "no shard_map found on the provided module (looked at "
-                ".shard_map and .experimental.shard_map.shard_map)")
-        return fn
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map as exp_fn
-    return exp_fn
-
-
-def replication_kwarg(fn: Callable) -> Optional[str]:
-    """Which replication-check kwarg ``fn`` accepts: ``"check_vma"``
-    (new), ``"check_rep"`` (old), or ``None`` (neither — drop it)."""
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):
-        return None
-    for name in _REPLICATION_SPELLINGS:
-        if name in params:
-            return name
-    return None
-
-
-def _impl() -> Callable:
-    global _IMPL
-    if _IMPL is None:
-        _IMPL = resolve_shard_map()
-    return _IMPL
-
 
 def shard_map(f: Callable, *, mesh, in_specs, out_specs,
-              check_replication: bool = True,
-              _impl_override: Optional[Callable] = None) -> Callable:
-    """Version-portable ``shard_map``.
-
-    Identical semantics to jax's, with the unstable parts resolved:
-    import location and the replication-check kwarg spelling
-    (``check_replication`` maps onto whichever of the two the
-    installed jax understands).
-    """
-    impl = _impl_override if _impl_override is not None else _impl()
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    spelling = replication_kwarg(impl)
-    if spelling is not None:
-        kwargs[spelling] = check_replication
-    return impl(f, **kwargs)
+              check_replication: bool = True) -> Callable:
+    """``jax.shard_map`` with ``check_replication`` → ``check_vma``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         check_vma=check_replication)

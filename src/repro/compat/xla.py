@@ -91,8 +91,9 @@ def _subprocess_accepts(flags: Sequence[str],
     flag-rejection signature) — inconclusive is never cached."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = " ".join(flags)
-    # CPU suffices for flag parsing and avoids slow device discovery.
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # CPU suffices for flag parsing, and the child must never reach for
+    # an accelerator: the parent may hold the chip (one process per chip)
+    env["JAX_PLATFORMS"] = "cpu"
     try:
         proc = subprocess.run([sys.executable, "-c", _PROBE_SNIPPET],
                               env=env, capture_output=True,

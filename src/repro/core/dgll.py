@@ -231,22 +231,28 @@ class DistState(NamedTuple):
 
 def init_dist_state(mesh: Mesh, n: int, cap: int, hc_cap: int) -> DistState:
     q = mesh.devices.size
-    table = LabelTable(
-        hubs=jnp.full((q, n, cap), -1, dtype=jnp.int32),
-        dist=jnp.full((q, n, cap), jnp.inf, dtype=jnp.float32),
-        count=jnp.zeros((q, n), dtype=jnp.int32),
-    )
     sh = NamedSharding(mesh, P("node"))
-    table = LabelTable(*(jax.device_put(x, sh) for x in table))
+    table = LabelTable(*(jax.device_put(x, sh)
+                         for x in empty_partitions(q, n, cap)))
     hc = lbl.empty(n, hc_cap)
     rep = NamedSharding(mesh, P())
     hc = LabelTable(*(jax.device_put(x, rep) for x in hc))
     return DistState(table=table, hc=hc)
 
 
+def empty_partitions(q: int, n: int, cap: int) -> LabelTable:
+    """An empty ``[q, n, cap]`` partitioned table in host memory —
+    callers place it with a node sharding, so each device receives only
+    its own partition and no chip ever holds the whole table."""
+    return LabelTable(hubs=np.full((q, n, cap), -1, dtype=np.int32),
+                      dist=np.full((q, n, cap), np.inf, dtype=np.float32),
+                      count=np.zeros((q, n), dtype=np.int32))
+
+
 def merge_partitions(table: LabelTable) -> LabelTable:
     """Collapse a [q, n, L] partitioned table into one [n, q*L] table
-    (host-side; used for validation and QLSN)."""
+    in host memory (numpy arrays; used for validation, re-homing into
+    a store, and QLSN)."""
     q, n, L = table.hubs.shape
     hubs = np.asarray(table.hubs).transpose(1, 0, 2).reshape(n, q * L)
     dist = np.asarray(table.dist).transpose(1, 0, 2).reshape(n, q * L)
@@ -255,8 +261,7 @@ def merge_partitions(table: LabelTable) -> LabelTable:
     hubs = np.take_along_axis(hubs, order, axis=1)
     dist = np.take_along_axis(dist, order, axis=1)
     count = valid.sum(axis=1).astype(np.int32)
-    return LabelTable(jnp.asarray(hubs), jnp.asarray(dist),
-                      jnp.asarray(count))
+    return LabelTable(hubs, dist, count)
 
 
 def dgll_chl(g, rank: np.ndarray, *, mesh: Optional[Mesh] = None,

@@ -11,9 +11,9 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
-import jax.numpy as jnp
 import numpy as np
 
+from repro.core import labels as lbl
 from repro.core.labels import LabelTable
 
 
@@ -41,15 +41,6 @@ def query_directed(l_out: LabelTable, l_in: LabelTable, u, v, *,
 
     ``with_hub=True`` also returns the witnessing hub id per query
     (-1 when the label sets are disjoint)."""
-    hu, du = l_out.hubs[u], l_out.dist[u]
-    hv, dv = l_in.hubs[v], l_in.dist[v]
-    match = (hu[:, :, None] == hv[:, None, :]) & (hu[:, :, None] >= 0)
-    dd = jnp.where(match, du[:, :, None] + dv[:, None, :], jnp.inf)
-    best = jnp.min(dd, axis=(1, 2))
-    if not with_hub:
-        return best
-    flat = jnp.argmin(dd.reshape(dd.shape[0], -1), axis=-1)
-    bi = flat // dd.shape[2]
-    hub = jnp.where(jnp.isfinite(best),
-                    jnp.take_along_axis(hu, bi[:, None], axis=1)[:, 0], -1)
-    return best, hub
+    best, hub = lbl.intersect_rows(l_out.hubs[u], l_out.dist[u],
+                                   l_in.hubs[v], l_in.dist[v])
+    return (best, hub) if with_hub else best

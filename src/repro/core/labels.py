@@ -127,6 +127,28 @@ def cover_best_rank(table: LabelTable, hmap: Array, rank: Array,
     return jnp.max(cand, axis=-1)                           # [B, n]
 
 
+def intersect_rows(hu: Array, du: Array, hv: Array, dv: Array
+                   ) -> Tuple[Array, Array]:
+    """Min over common hubs of gathered label rows ``[Q, L]`` each.
+
+    Returns (distance f32 [Q], best-hub id i32 [Q]; -1 when disjoint).
+    The ``[Q, L, L]`` candidate cube feeds exactly one reduction (over
+    v's slots), so XLA fuses it away and only the ``[Q, L]`` per-u-slot
+    minima are materialized. The witnessing hub is the first u slot
+    attaining the overall min — the slot a flattened argmin over the
+    cube picks, so the hub is bit-identical to that formulation.
+    """
+    match = (hu[:, :, None] == hv[:, None, :]) & (hu[:, :, None] >= 0)
+    dd = jnp.where(match, du[:, :, None] + dv[:, None, :], jnp.inf)
+    row = jnp.min(dd, axis=2)                      # [Q, L]
+    best = jnp.min(row, axis=1)
+    bi = jnp.argmin(row, axis=1)
+    hub = jnp.where(jnp.isfinite(best),
+                    jnp.take_along_axis(hu, bi[:, None], axis=1)[:, 0], -1)
+    return best, hub
+
+
+@jax.jit
 def query_pairs(table: LabelTable, u: Array, v: Array
                 ) -> Tuple[Array, Array]:
     """Batched PPSD query: min over common hubs of d(u,x)+d(v,x).
@@ -134,17 +156,8 @@ def query_pairs(table: LabelTable, u: Array, v: Array
     Returns (distance f32 [Q], best-hub id i32 [Q]; -1 when disjoint).
     Pure-jnp reference for the ``label_query`` kernel.
     """
-    hu, du = table.hubs[u], table.dist[u]          # [Q, L]
-    hv, dv = table.hubs[v], table.dist[v]
-    match = (hu[:, :, None] == hv[:, None, :]) & (hu[:, :, None] >= 0)
-    dd = du[:, :, None] + dv[:, None, :]
-    dd = jnp.where(match, dd, jnp.inf)
-    best = jnp.min(dd, axis=(1, 2))
-    flat = jnp.argmin(dd.reshape(dd.shape[0], -1), axis=-1)
-    bi = flat // dd.shape[2]
-    hub = jnp.where(jnp.isfinite(best),
-                    jnp.take_along_axis(hu, bi[:, None], axis=1)[:, 0], -1)
-    return best, hub
+    return intersect_rows(table.hubs[u], table.dist[u],
+                          table.hubs[v], table.dist[v])
 
 
 def merge(a: LabelTable, b: LabelTable) -> Tuple[LabelTable, Array]:

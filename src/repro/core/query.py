@@ -120,10 +120,10 @@ def qdol_build(table: LabelTable, layout: QdolLayout, mesh: Mesh
         hubs[k, :len(verts)] = th[verts]
         dist[k, :len(verts)] = td[verts]
         slot[k, verts] = np.arange(len(verts), dtype=np.int32)
-    sh = NamedSharding(mesh, P("node"))
-    return QdolStore(hubs=jax.device_put(jnp.asarray(hubs), sh),
-                     dist=jax.device_put(jnp.asarray(dist), sh),
-                     slot=jax.device_put(jnp.asarray(slot), sh))
+    sh = NamedSharding(mesh, P("node"))      # placed from host memory
+    return QdolStore(hubs=jax.device_put(hubs, sh),
+                     dist=jax.device_put(dist, sh),
+                     slot=jax.device_put(slot, sh))
 
 
 def qdol_fn(mesh: Mesh, layout: QdolLayout):

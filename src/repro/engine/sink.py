@@ -187,15 +187,12 @@ class MeshTableSink:
         self.n = int(n)
         self.cap = int(cap)
         self.q = int(mesh.devices.size)
+        from repro.core.dgll import empty_partitions
         self._node_sh = NamedSharding(mesh, P("node"))
-        table = LabelTable(
-            hubs=jnp.full((self.q, self.n, self.cap), -1,
-                          dtype=jnp.int32),
-            dist=jnp.full((self.q, self.n, self.cap), jnp.inf,
-                          dtype=jnp.float32),
-            count=jnp.zeros((self.q, self.n), dtype=jnp.int32))
-        self.table = LabelTable(*(jax.device_put(x, self._node_sh)
-                                  for x in table))
+        # placed from host memory: each device receives its partition
+        self.table = LabelTable(*(
+            jax.device_put(x, self._node_sh)
+            for x in empty_partitions(self.q, self.n, self.cap)))
         self._host_ovf = False
 
     def set_table(self, table: LabelTable) -> None:
@@ -227,5 +224,5 @@ class MeshTableSink:
                                        np.asarray(arrays["dist"]),
                                        self.cap)
         self.table = LabelTable(
-            *(jax.device_put(jnp.asarray(x), self._node_sh)
+            *(jax.device_put(x, self._node_sh)
               for x in (hubs, dist, np.asarray(arrays["count"]))))

@@ -27,6 +27,7 @@ from __future__ import annotations
 import time
 from typing import Optional
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import labels as lbl
@@ -96,8 +97,10 @@ def build(g, rank: np.ndarray, plan: Optional[BuildPlan] = None, *,
     if plan.algo != "pll-ref":           # the host oracle runs no sweeps
         from repro.kernels.ell_relax import (kernel_fits,
                                              resolve_use_kernel,
+                                             sweep_form,
                                              vmem_fallback_note,
                                              windowed_note)
+        notes.append(sweep_form(n))
         if resolve_use_kernel(None) and not kernel_fits(n):
             # surface the windowing decision in the report: single-host
             # builds stream the source-windowed kernel; the distributed
@@ -170,7 +173,7 @@ def build(g, rank: np.ndarray, plan: Optional[BuildPlan] = None, *,
     else:
         if res.sink.kind == "mesh":
             from repro.core.dgll import merge_partitions
-            table = merge_partitions(res.sink.table)
+            table = merge_partitions(res.sink.table)    # host memory
         else:
             table = res.sink.table()
         if plan.store == "sharded":
@@ -182,7 +185,7 @@ def build(g, rank: np.ndarray, plan: Optional[BuildPlan] = None, *,
                 exact=plan.quant_exact,
                 shards=_resolve_shards(plan, res.extras))
         else:
-            store = DenseStore(table)
+            store = DenseStore(lbl.LabelTable(*map(jnp.asarray, table)))
     if isinstance(store, CompressedStore):
         if store.exact:
             notes.append(f"quant: codec={store.codec} exact "

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.labels import LabelTable
+from repro.core.labels import LabelTable, intersect_rows
 from repro.ft.inject import fault_site
 from repro.index.quant import (decode_dist_jnp, decode_dist_np,
                                delta_decode_rows_jnp,
@@ -59,15 +59,7 @@ def _shard_query(dhub, dcode, count, order, scale, u, v, *, codec):
     hv = delta_decode_rows_jnp(dhub[v], count[v], order)
     du = decode_dist_jnp(dcode[u], codec, scale)
     dv = decode_dist_jnp(dcode[v], codec, scale)
-    match = (hu[:, :, None] == hv[:, None, :]) & (hu[:, :, None] >= 0)
-    dd = jnp.where(match, du[:, :, None] + dv[:, None, :], jnp.inf)
-    best = jnp.min(dd, axis=(1, 2))
-    flat = jnp.argmin(dd.reshape(dd.shape[0], -1), axis=-1)
-    bi = flat // dd.shape[2]
-    hub = jnp.where(jnp.isfinite(best),
-                    jnp.take_along_axis(hu, bi[:, None], axis=1)[:, 0],
-                    -1)
-    return best, hub
+    return intersect_rows(hu, du, hv, dv)
 
 
 class CompressedStore:

@@ -8,12 +8,15 @@ intersections plus one cross-shard ``min`` — exact, because every
 common hub of a pair is intersected in exactly one shard and f32
 ``min`` is order-insensitive.
 
-Execution: the stacked ``[K, n, Ls]`` arrays answer queries through a
-vmapped partial-min + reduce on one device (the time-multiplexed
-path), and :meth:`as_partitioned` places shard ``k`` on device ``k``
-of a mesh so ``repro.core.query.qfdl_fn`` runs the same computation as
-a real ``shard_map`` + ``pmin`` — the QFDL mode served from the
-store's own layout rather than a synthesized copy.
+Residency: the stacked ``[K, n, Ls]`` arrays are kept in host memory
+and placed on a device only by the path that serves from it. The
+single-device path copies them to the default device on first use and
+answers through a vmapped partial-min + reduce (time-multiplexed);
+:meth:`as_partitioned` places shard ``k`` on device ``k`` of a mesh
+straight from host memory — no chip ever holds the whole table — so
+``repro.core.query.qfdl_fn`` runs the same computation as a real
+``shard_map`` + ``pmin``, the QFDL mode served from the store's own
+layout rather than a synthesized copy.
 """
 
 from __future__ import annotations
@@ -56,17 +59,19 @@ class ShardedStore:
 
     def __init__(self, hubs, dist, count):
         """``hubs`` i32 [K, n, Ls], ``dist`` f32 [K, n, Ls],
-        ``count`` i32 [K, n] — shard-major stacked label arrays."""
-        self.hubs = jnp.asarray(hubs)
-        self.dist = jnp.asarray(dist)
-        self.count = jnp.asarray(count)
+        ``count`` i32 [K, n] — shard-major stacked label arrays (kept
+        in host memory)."""
+        self.hubs = np.asarray(hubs, dtype=np.int32)
+        self.dist = np.asarray(dist, dtype=np.float32)
+        self.count = np.asarray(count, dtype=np.int32)
         if self.hubs.ndim != 3 or self.count.ndim != 2:
             raise ValueError("ShardedStore wants [K, n, Ls] labels and "
                              "[K, n] counts")
-        # per-shard [n, Ls] slices, materialized lazily for the routed
-        # serving path (slicing the stacked arrays per query would pay
-        # an O(n·Ls) device copy on every launch)
+        self._on_device: Optional[Tuple[jax.Array, ...]] = None
+        # per-shard [n, Ls] device arrays, placed lazily for the routed
+        # serving path (one transfer per shard, not one per launch)
         self._shard_views: Dict[int, Tuple] = {}
+        self._placed: Dict[object, LabelTable] = {}
 
     # ---------------------------------------------------- protocol
 
@@ -84,7 +89,7 @@ class ShardedStore:
 
     @property
     def total_labels(self) -> int:
-        return int(np.asarray(jnp.sum(self.count)))
+        return int(self.count.sum())
 
     def query(self, u, v) -> Tuple[np.ndarray, np.ndarray]:
         d, h = self.query_device(u, v)
@@ -94,15 +99,19 @@ class ShardedStore:
         """Full K-shard reduction, staying on device (jitted) — the
         serving-path variant of :meth:`query` (no host round trip per
         batch)."""
+        if self._on_device is None:
+            self._on_device = (jnp.asarray(self.hubs),
+                               jnp.asarray(self.dist),
+                               jnp.asarray(self.count))
         u = jnp.atleast_1d(jnp.asarray(u, jnp.int32))
         v = jnp.atleast_1d(jnp.asarray(v, jnp.int32))
-        return _stacked_query(self.hubs, self.dist, self.count, u, v)
+        return _stacked_query(*self._on_device, u, v)
 
     def shard_counts(self) -> np.ndarray:
         """Host ``[K, n]`` per-shard label counts — the routing table
         for per-shard query dispatch (shard k can contribute to
         ``(u, v)`` only when both endpoints hold labels in k)."""
-        return np.asarray(self.count)
+        return self.count
 
     def query_shard(self, k: int, u, v) -> Tuple[np.ndarray, np.ndarray]:
         """Partial PPSD mins over shard ``k`` only (jitted; +inf/-1
@@ -111,7 +120,8 @@ class ShardedStore:
         only +inf contributions from the cross-shard min."""
         views = self._shard_views.get(k)
         if views is None:
-            views = (self.hubs[k], self.dist[k], self.count[k])
+            views = (jnp.asarray(self.hubs[k]), jnp.asarray(self.dist[k]),
+                     jnp.asarray(self.count[k]))
             self._shard_views[k] = views
         u = jnp.atleast_1d(jnp.asarray(u, jnp.int32))
         v = jnp.atleast_1d(jnp.asarray(v, jnp.int32))
@@ -123,9 +133,7 @@ class ShardedStore:
             arrs for _, arrs in self.shard_arrays()).to_table()
 
     def shard_arrays(self) -> Iterator[Tuple[int, Dict[str, np.ndarray]]]:
-        hubs = np.asarray(self.hubs)
-        dist = np.asarray(self.dist)
-        count = np.asarray(self.count)
+        hubs, dist, count = self.hubs, self.dist, self.count
         for k in range(self.num_shards):
             # trim each shard to its own tight cap — per-shard files
             # should not pay the widest shard's padding
@@ -138,24 +146,27 @@ class ShardedStore:
 
     def shard_label_bytes(self) -> list:
         """Per-shard resident label bytes (capacity-planning view)."""
-        per = np.asarray(jnp.sum(self.count, axis=1))
-        return [int(c) * 8 for c in per]
+        return [int(c) * 8 for c in self.count.sum(axis=1)]
 
     # ------------------------------------------------------ serving
 
     def as_partitioned(self, mesh) -> LabelTable:
         """The stacked arrays as a mesh-placed ``[K, n, Ls]``
-        LabelTable (shard k on device k) for ``qfdl_fn`` — requires
-        ``mesh`` size == ``num_shards``."""
+        LabelTable (shard k on device k, placed from host memory) for
+        ``qfdl_fn`` — requires ``mesh`` size == ``num_shards``. Placed
+        once per mesh; later calls return the same arrays."""
         from jax.sharding import NamedSharding, PartitionSpec as P
         if int(mesh.devices.size) != self.num_shards:
             raise ValueError(
                 f"mesh has {int(mesh.devices.size)} devices but the "
                 f"store has {self.num_shards} shards")
-        sh = NamedSharding(mesh, P("node"))
-        return LabelTable(jax.device_put(self.hubs, sh),
-                          jax.device_put(self.dist, sh),
-                          jax.device_put(self.count, sh))
+        placed = self._placed.get(mesh)
+        if placed is None:
+            sh = NamedSharding(mesh, P("node"))
+            placed = LabelTable(*(jax.device_put(x, sh) for x in
+                                  (self.hubs, self.dist, self.count)))
+            self._placed[mesh] = placed
+        return placed
 
     # ------------------------------------------------- constructors
 

@@ -7,7 +7,7 @@ from repro.kernels.ell_relax.layout import (DEFAULT_VMEM_BUDGET,
                                             vmem_budget, window_plan)
 from repro.kernels.ell_relax.ops import (ELL_RELAX_ENV_VAR, ell_sweep,
                                          reset_warnings, resolve_sweep_backend,
-                                         resolve_use_kernel,
+                                         resolve_use_kernel, sweep_form,
                                          vmem_fallback_note,
                                          warn_vmem_fallback, windowed_note)
 from repro.kernels.ell_relax.ref import ell_sweep_bucketed_ref, ell_sweep_ref
@@ -15,7 +15,7 @@ from repro.kernels.ell_relax.ref import ell_sweep_bucketed_ref, ell_sweep_ref
 __all__ = [
     "ell_relax", "ell_relax_windowed",
     "ell_sweep", "ell_sweep_ref", "ell_sweep_bucketed_ref",
-    "resolve_use_kernel", "resolve_sweep_backend",
+    "resolve_use_kernel", "resolve_sweep_backend", "sweep_form",
     "kernel_fits", "max_window", "vmem_budget", "window_plan",
     "sweep_layout", "build_bucketed_ell", "clear_layout_cache",
     "BucketedEll", "WindowPlan",

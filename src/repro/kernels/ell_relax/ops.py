@@ -1,4 +1,4 @@
-"""Shape-safe wrappers around the fused ELL relaxation kernel.
+"""Sweep dispatch: the XLA form, or the fused ELL relaxation kernel.
 
 ``ell_sweep`` is the single entry point the sweep driver
 (`repro.sssp.relax`) calls: it pads every operand to tile multiples,
@@ -11,13 +11,12 @@ Backend selection (``use_kernel``):
   ``pallas_call`` dispatcher, so `REPRO_PALLAS_BACKEND` still decides
   compiled-TPU vs interpreter execution);
 - ``False`` — always run the jnp reference;
-- ``None``  — auto: the kernel wherever the compat layer resolves a
-  *compiled* Pallas backend (TPU), the jnp reference where Pallas
-  would only be interpreter emulation (CPU/GPU) — emulating the hot
-  loop per sweep is strictly slower than the fused-XLA reference.
-  ``REPRO_ELL_RELAX=kernel|ref|auto`` overrides the auto choice
-  (e.g. ``kernel`` + ``REPRO_PALLAS_BACKEND=interpret`` exercises the
-  emulated kernel path end-to-end, as CI's bench smoke does).
+- ``None``  — auto: the jnp reference (the XLA form) on every
+  platform. Compiled for a TPU, Mosaic refuses the kernel's in-VMEM
+  gather, so the chip path is the XLA form by decision, not by a
+  runtime fallback. ``REPRO_ELL_RELAX=kernel`` selects the kernel
+  (with ``REPRO_PALLAS_BACKEND=interpret`` it runs emulated
+  end-to-end, as CI's bench smoke does).
 
 VMEM windowing: the kernel gathers from ``[BB, W]`` source-plane
 slices. When the whole plane fits the budget
@@ -103,28 +102,43 @@ def warn_vmem_fallback(n: int, reason: str = "traced") -> bool:
     return True
 
 
-def resolve_use_kernel(use_kernel: bool | None = None, *,
-                       interpret: bool | None = None) -> bool:
-    """Kernel-vs-reference dispatch for the relaxation sweep."""
+def resolve_use_kernel(use_kernel: bool | None = None) -> bool:
+    """Kernel-vs-XLA dispatch for the relaxation sweep.
+
+    ``auto`` is the XLA form (`ell_sweep_ref`) on every platform:
+    Mosaic refuses the kernel's in-VMEM gather (``jnp.take`` of a
+    ``[BB, W]`` plane by a ``[BN, DK]`` index tile) when compiling for
+    a TPU — "Shape mismatch in input, indices and output" — so the
+    kernel stays reachable only when asked for explicitly
+    (``use_kernel=True`` or ``REPRO_ELL_RELAX=kernel``, which the
+    interpreter-mode parity tests use)."""
     if use_kernel is not None:
         return bool(use_kernel)
     mode = os.environ.get(ELL_RELAX_ENV_VAR, "auto").strip().lower()
     if mode == "kernel":
         return True
-    if mode == "ref":
-        return False
-    if mode not in ("", "auto"):
+    if mode not in ("", "auto", "ref"):
         raise ValueError(f"{ELL_RELAX_ENV_VAR}={mode!r}; expected "
                          "auto, kernel, or ref")
-    # auto: fused kernel on the compiled backend; under interpreter
-    # emulation the jnp reference IS the fast path
-    return not resolve_interpret(interpret)
+    return False
+
+
+def sweep_form(n: int, use_kernel: bool | None = None) -> str:
+    """One line naming the sweep form a build over ``n`` vertices runs
+    (for `BuildReport.notes` and launch logs)."""
+    if not resolve_use_kernel(use_kernel):
+        return ("sweep: xla (ell_sweep_ref, one whole-plane gather; "
+                "windows=1) — the Pallas ell_relax kernel is not used: "
+                "Mosaic refuses its in-VMEM gather on TPU")
+    plan = window_plan(n)
+    return (f"sweep: pallas ell_relax kernel "
+            f"({'source-windowed' if plan.num_windows > 1 else 'dense'}"
+            f"; windows={plan.num_windows})")
 
 
 def resolve_sweep_backend(ell_src, ell_w, *,
                           use_kernel: bool | None = None,
-                          layout: Optional[BucketedEll] = None,
-                          interpret: bool | None = None
+                          layout: Optional[BucketedEll] = None
                           ) -> Tuple[bool, Optional[BucketedEll]]:
     """One place that decides how a sweep over this adjacency runs.
 
@@ -137,7 +151,7 @@ def resolve_sweep_backend(ell_src, ell_w, *,
     when the adjacency is concrete, warning + falling back to the
     reference when it is traced.
     """
-    kern = resolve_use_kernel(use_kernel, interpret=interpret)
+    kern = resolve_use_kernel(use_kernel)
     if not kern:
         return False, None
     if layout is not None and layout.num_windows > 1:
@@ -191,8 +205,7 @@ def ell_sweep(dist, mrank, prop, alive, ell_src, ell_w, rank, *,
     """
     interp = resolve_interpret(interpret)
     kern, layout = resolve_sweep_backend(
-        ell_src, ell_w, use_kernel=use_kernel, layout=layout,
-        interpret=interp)
+        ell_src, ell_w, use_kernel=use_kernel, layout=layout)
     if kern and layout is not None:
         return _ell_sweep_windowed_jit(dist, mrank, prop, alive,
                                        layout, rank, interpret=interp)

@@ -163,4 +163,6 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compat import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -76,10 +76,9 @@ def partition_by_hub(table: LabelTable, rank: np.ndarray, mesh
     hubs, dist, count = hub_partition_arrays(
         np.asarray(table.hubs), np.asarray(table.dist), rank, q,
         shard_cap=L)
-    sh = NamedSharding(mesh, P("node"))
-    return LabelTable(jax.device_put(jnp.asarray(hubs), sh),
-                      jax.device_put(jnp.asarray(dist), sh),
-                      jax.device_put(jnp.asarray(count), sh))
+    sh = NamedSharding(mesh, P("node"))      # placed from host memory
+    return LabelTable(*(jax.device_put(x, sh)
+                        for x in (hubs, dist, count)))
 
 
 def _as_store(store_or_table: Union[LabelStore, LabelTable]) -> LabelStore:

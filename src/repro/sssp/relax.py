@@ -29,10 +29,11 @@ shortest path the chain of vertices unblocks inductively from the root
 Execution model (this is the single hottest path in the repo):
 
 - each sweep runs through ``repro.kernels.ell_relax.ell_sweep`` — the
-  fused Pallas ELL (min,+,max-rank) kernel on the compiled backend,
-  the bit-identical jnp reference otherwise (``use_kernel`` /
-  ``REPRO_ELL_RELAX`` override; `REPRO_PALLAS_BACKEND` picks the
-  Pallas execution mode underneath);
+  jnp reference compiled by XLA on every platform (Mosaic refuses the
+  fused Pallas kernel's gather for TPU), or the bit-identical fused
+  Pallas ELL (min,+,max-rank) kernel when asked for (``use_kernel`` /
+  ``REPRO_ELL_RELAX=kernel``; `REPRO_PALLAS_BACKEND` picks the Pallas
+  execution mode underneath);
 - sweeps are **frontier-gated** (default on the kernel path): only
   vertices whose (dist, mrank) changed last sweep — plus vertices
   that just *unblocked*, whose pending contribution was masked while
@@ -65,7 +66,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.ell_relax import (BucketedEll, ell_sweep,
-                                     resolve_sweep_backend, sweep_layout)
+                                     resolve_sweep_backend,
+                                     resolve_use_kernel, sweep_layout)
 
 Array = jax.Array
 BlockFn = Callable[[Array, Array], Array]   # (dist [B,n], roots [B]) -> blocked [B,n]
@@ -83,8 +85,11 @@ def ell_layout(ell_src: Array, ell_w: Array, *,
     policies, `plant_batch`) build this once *eagerly* — the adjacency
     is a tracer inside their jitted step functions, where bucketing is
     impossible — and thread it through ``layout=``. Returns None as
-    well when the adjacency is itself traced.
+    well when the adjacency is itself traced, and when the sweep runs
+    its XLA form (the layout only feeds the Pallas kernel).
     """
+    if not resolve_use_kernel(None):
+        return None
     return sweep_layout(ell_src, ell_w, max_window=max_window)
 
 
@@ -158,8 +163,8 @@ def batched_sssp_maxrank(
         jnp path (XLA cannot skip the overshoot sweeps, so striding
         only adds work there).
       use_kernel: fused Pallas ELL kernel vs jnp reference; ``None`` =
-        compat-resolved dispatch (``REPRO_ELL_RELAX`` /
-        ``REPRO_PALLAS_BACKEND`` honored).
+        `resolve_use_kernel` (the jnp reference unless
+        ``REPRO_ELL_RELAX=kernel``).
       frontier_gating: mask propagation down to the active frontier
         and retire converged trees. Default: follows the kernel
         decision — gating lets the kernel skip retired tiles, while

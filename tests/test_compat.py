@@ -1,10 +1,9 @@
 """Unit tests for ``repro.compat`` — the only sanctioned unstable-JAX
 surface in the repo.
 
-Each resolver is exercised against BOTH API spellings (old 0.4.x and
-current) via stand-in modules/callables, then once against the
-actually-installed jax. A hygiene test scans the tree to keep direct
-unstable imports from creeping back in outside ``src/repro/compat/``.
+Each helper is exercised against the installed jax. A hygiene test
+scans the tree to keep direct unstable imports from creeping back in
+outside ``src/repro/compat/``.
 
 Forbidden spellings are assembled by string concatenation throughout
 so this file itself stays clean under that same scan (and under the
@@ -21,112 +20,15 @@ import jax
 import jax.numpy as jnp
 
 from repro import compat
-from repro.compat import meshes, pallas, shardmap, version, xla
+from repro.compat import pallas, xla
 
-NEW_REP_KWARG = "check_" + "vma"
-OLD_REP_KWARG = "check_" + "rep"
 NEW_CP_NAME = "Compiler" + "Params"
 OLD_CP_NAME = "TPU" + "Compiler" + "Params"
 
 
 # --------------------------------------------------------------------
-# version parsing
+# shard_map / make_mesh
 # --------------------------------------------------------------------
-
-@pytest.mark.parametrize("raw,want", [
-    ("0.4.37", (0, 4, 37)),
-    ("0.8.0.dev20250101", (0, 8, 0)),
-    ("1.2", (1, 2, 0)),
-    ("0.5.3rc1", (0, 5, 3)),
-])
-def test_version_tuple(raw, want):
-    assert version.version_tuple(raw) == want
-
-
-def test_installed_version_parsed():
-    assert compat.JAX_VERSION >= (0, 4, 37)
-
-
-# --------------------------------------------------------------------
-# shard_map: location resolution
-# --------------------------------------------------------------------
-
-def test_resolve_prefers_top_level():
-    def top(*a, **k):
-        return "top"
-
-    def exp(*a, **k):
-        return "exp"
-
-    mod = types.SimpleNamespace(
-        shard_map=top,
-        experimental=types.SimpleNamespace(
-            shard_map=types.SimpleNamespace(shard_map=exp)))
-    assert shardmap.resolve_shard_map(mod) is top
-
-
-def test_resolve_falls_back_to_experimental():
-    def exp(*a, **k):
-        return "exp"
-
-    mod = types.SimpleNamespace(
-        experimental=types.SimpleNamespace(
-            shard_map=types.SimpleNamespace(shard_map=exp)))
-    assert shardmap.resolve_shard_map(mod) is exp
-
-
-def test_resolve_missing_raises():
-    with pytest.raises(AttributeError):
-        shardmap.resolve_shard_map(types.SimpleNamespace())
-
-
-def test_resolve_installed_jax():
-    assert callable(shardmap.resolve_shard_map())
-
-
-# --------------------------------------------------------------------
-# shard_map: replication-kwarg translation (both spellings)
-# --------------------------------------------------------------------
-
-def _fake_impl(kwarg_name):
-    """A stand-in shard_map whose signature carries ``kwarg_name``."""
-    captured = {}
-    src = (f"def impl(f, *, mesh, in_specs, out_specs, "
-           f"{kwarg_name}=True):\n"
-           f"    captured.update(mesh=mesh, flag={kwarg_name})\n"
-           f"    return 'wrapped'\n")
-    ns = {"captured": captured}
-    exec(src, ns)
-    return ns["impl"], captured
-
-
-@pytest.mark.parametrize("spelling", [NEW_REP_KWARG, OLD_REP_KWARG])
-def test_shard_map_translates_replication_kwarg(spelling):
-    impl, captured = _fake_impl(spelling)
-    assert shardmap.replication_kwarg(impl) == spelling
-    out = compat.shard_map(lambda x: x, mesh="M", in_specs=(),
-                           out_specs=(), check_replication=False,
-                           _impl_override=impl)
-    assert out == "wrapped"
-    assert captured["flag"] is False
-    assert captured["mesh"] == "M"
-
-
-def test_shard_map_drops_kwarg_when_signature_has_neither():
-    def impl(f, *, mesh, in_specs, out_specs):
-        return "bare"
-
-    assert shardmap.replication_kwarg(impl) is None
-    out = compat.shard_map(lambda x: x, mesh=None, in_specs=(),
-                           out_specs=(), check_replication=False,
-                           _impl_override=impl)
-    assert out == "bare"
-
-
-def test_installed_jax_accepts_one_spelling():
-    spelling = shardmap.replication_kwarg(shardmap.resolve_shard_map())
-    assert spelling in (NEW_REP_KWARG, OLD_REP_KWARG)
-
 
 def test_shard_map_real_roundtrip():
     from jax.sharding import PartitionSpec as P
@@ -137,101 +39,42 @@ def test_shard_map_real_roundtrip():
                                   [1, 2, 3])
 
 
-# --------------------------------------------------------------------
-# make_mesh: axis_types signature drift
-# --------------------------------------------------------------------
-
-class _FakeAxisType:
-    Auto = "AUTO"
-    Explicit = "EXPLICIT"
-    Manual = "MANUAL"
-
-
-def _new_make_mesh(shape, names, *, axis_types=None, devices=None):
-    return None
-
-
-def _old_make_mesh(shape, names, *, devices=None):
-    return None
-
-
-def test_mesh_axis_kwargs_new_api():
-    kw = meshes.mesh_axis_kwargs(2, make_mesh_fn=_new_make_mesh,
-                                 axis_type_cls=_FakeAxisType)
-    assert kw == {"axis_types": ("AUTO", "AUTO")}
-    kw = meshes.mesh_axis_kwargs(1, axis_types=("explicit",),
-                                 make_mesh_fn=_new_make_mesh,
-                                 axis_type_cls=_FakeAxisType)
-    assert kw == {"axis_types": ("EXPLICIT",)}
-
-
-def test_mesh_axis_kwargs_old_api_drops_kwarg():
-    # no enum at all (jax 0.4.x)
-    assert meshes.mesh_axis_kwargs(2, make_mesh_fn=_old_make_mesh,
-                                   axis_type_cls=None) == {}
-    # enum exists but make_mesh predates the kwarg (mid-transition)
-    assert meshes.mesh_axis_kwargs(2, make_mesh_fn=_old_make_mesh,
-                                   axis_type_cls=_FakeAxisType) == {}
-
-
-def test_mesh_axis_kwargs_validates():
-    with pytest.raises(ValueError):
-        meshes.mesh_axis_kwargs(2, axis_types=("auto",),
-                                make_mesh_fn=_new_make_mesh,
-                                axis_type_cls=_FakeAxisType)
-    with pytest.raises(ValueError):
-        meshes.mesh_axis_kwargs(1, axis_types=("bogus",),
-                                make_mesh_fn=_new_make_mesh,
-                                axis_type_cls=_FakeAxisType)
-
-
 def test_make_mesh_installed_jax():
     m = compat.make_mesh((1,), ("node",))
     assert m.axis_names == ("node",)
     assert m.devices.size == 1
 
 
+def test_make_mesh_axes_are_auto():
+    m = compat.make_mesh((1,), ("node",))
+    assert [t.name for t in m.axis_types] == ["Auto"]
+
+
 # --------------------------------------------------------------------
-# Pallas: compiler-params class drift + backend dispatch
+# Pallas: compiler params + backend dispatch
 # --------------------------------------------------------------------
 
-def test_compiler_params_both_spellings():
-    new_cls = type(NEW_CP_NAME, (), {})
-    old_cls = type(OLD_CP_NAME, (), {})
-    mod_new = types.SimpleNamespace(**{NEW_CP_NAME: new_cls})
-    mod_old = types.SimpleNamespace(**{OLD_CP_NAME: old_cls})
-    mod_both = types.SimpleNamespace(**{NEW_CP_NAME: new_cls,
-                                        OLD_CP_NAME: old_cls})
-    assert pallas.compiler_params_cls(mod_new) is new_cls
-    assert pallas.compiler_params_cls(mod_old) is old_cls
-    assert pallas.compiler_params_cls(mod_both) is new_cls   # prefer new
-    assert pallas.compiler_params_cls(types.SimpleNamespace()) is None
+def test_pallas_call_packs_compiler_params_for_tpu(monkeypatch):
+    """On a TPU target the dispatcher turns ``dimension_semantics``
+    into the TPU compiler params (read through the steerable
+    ``target_platform`` hook, not the process's own backend)."""
+    seen = {}
 
+    def fake_pallas_call(kernel, *, out_shape, interpret, **kw):
+        seen.update(kw, interpret=interpret)
+        return "called"
 
-def test_tpu_compiler_params_absent_returns_none():
-    out = pallas.tpu_compiler_params(
-        pltpu_module=types.SimpleNamespace(),
-        dimension_semantics=("parallel",))
-    assert out is None
-
-
-def test_tpu_compiler_params_drops_unknown_kwargs():
-    class Params:
-        def __init__(self, dimension_semantics=None):
-            self.dimension_semantics = dimension_semantics
-
-    mod = types.SimpleNamespace(**{NEW_CP_NAME: Params})
-    out = pallas.tpu_compiler_params(
-        pltpu_module=mod, dimension_semantics=("parallel",),
-        vmem_limit_bytes=1 << 20)
-    assert out.dimension_semantics == ("parallel",)
-
-
-def test_tpu_compiler_params_installed_jax():
-    params = compat.tpu_compiler_params(
+    monkeypatch.setattr(pallas, "target_platform", lambda: "tpu")
+    monkeypatch.setattr(pallas.pl, "pallas_call", fake_pallas_call)
+    monkeypatch.delenv(pallas.BACKEND_ENV_VAR, raising=False)
+    out = compat.pallas_call(
+        lambda x_ref, o_ref: None, grid=(1,),
+        out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
         dimension_semantics=("parallel", "arbitrary"))
-    assert params is not None
-    assert type(params).__name__ in (NEW_CP_NAME, OLD_CP_NAME)
+    assert out == "called"
+    assert seen["interpret"] is False
+    assert tuple(seen["compiler_params"].dimension_semantics) == (
+        "parallel", "arbitrary")
 
 
 def test_resolve_interpret_explicit_wins():
@@ -421,6 +264,23 @@ def test_pallas_call_compiled_non_tpu_rejects_arbitrary_semantics():
             dimension_semantics=("arbitrary",), interpret=False)
 
 
+def test_flag_probe_child_runs_on_cpu(monkeypatch):
+    """The probe child must never reach for an accelerator, even when
+    the parent inherited ``JAX_PLATFORMS=tpu``: the parent may hold the
+    chip, and a chip serves one process."""
+    seen = {}
+
+    def fake_run(cmd, env, **kw):
+        seen.update(env)
+        return types.SimpleNamespace(returncode=0, stderr=b"")
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.setattr(xla.subprocess, "run", fake_run)
+    assert xla._subprocess_accepts(["--xla_a=1"]) is True
+    assert seen["JAX_PLATFORMS"] == "cpu"
+    assert seen["XLA_FLAGS"] == "--xla_a=1"
+
+
 def test_host_device_flags_contents():
     flags = xla.host_device_flags(8)
     assert flags[0] == "--xla_force_host_platform_device_count=8"
@@ -430,8 +290,6 @@ def test_host_device_flags_contents():
 def test_capabilities_report():
     caps = compat.capabilities()
     assert caps["jax_version"] == jax.__version__
-    assert caps["replication_kwarg"] in (NEW_REP_KWARG, OLD_REP_KWARG,
-                                         None)
     assert isinstance(caps["pallas_interpret"], bool)
 
 

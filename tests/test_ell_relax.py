@@ -26,6 +26,7 @@ import pytest
 
 import jax.numpy as jnp
 
+from repro.compat import pallas as pallas_compat
 from repro.graphs import grid_road, random_connected, scale_free
 from repro.graphs.ranking import degree_ranking, random_ranking
 from repro.kernels.ell_relax import (ELL_RELAX_ENV_VAR,
@@ -235,16 +236,20 @@ def test_resolve_use_kernel_env(monkeypatch):
     assert resolve_use_kernel(None) is True
     monkeypatch.setenv(ELL_RELAX_ENV_VAR, "ref")
     assert resolve_use_kernel(None) is False
+    # auto is the XLA form on every platform (Mosaic refuses the
+    # kernel's gather for TPU), whatever the Pallas backend resolves to
     monkeypatch.setenv(ELL_RELAX_ENV_VAR, "auto")
-    assert resolve_use_kernel(None, interpret=False) is True
-    assert resolve_use_kernel(None, interpret=True) is False
+    assert resolve_use_kernel(None) is False
+    monkeypatch.setattr(pallas_compat, "target_platform", lambda: "tpu")
+    assert resolve_use_kernel(None) is False
     monkeypatch.setenv(ELL_RELAX_ENV_VAR, "bogus")
     with pytest.raises(ValueError):
         resolve_use_kernel(None)
     monkeypatch.delenv(ELL_RELAX_ENV_VAR, raising=False)
+    assert resolve_use_kernel(None) is False
     # explicit arg always wins
-    assert resolve_use_kernel(True, interpret=True) is True
-    assert resolve_use_kernel(False, interpret=False) is False
+    assert resolve_use_kernel(True) is True
+    assert resolve_use_kernel(False) is False
 
 
 def test_explicit_env_kernel_end_to_end(monkeypatch):
