@@ -2,6 +2,7 @@
 
     python chip_smoke.py             # one chip: PLaNT build, QLSN serving
     python chip_smoke.py --chips 4   # four chips: Hybrid build, QFDL serving
+    python chip_smoke.py --side 224  # another grid side
 
 One chip (the default) drives the main path through the entry points a
 user calls: a road graph at the shape of the 9th DIMACS Implementation
@@ -42,15 +43,20 @@ import numpy as np  # noqa: E402
 
 #: One-chip graph side. The deployment this mirrors is NY scale
 #: (512 x 512 = 262,144 vertices). PLaNT builds every vertex's full
-#: shortest-path tree with dense Bellman-Ford sweeps, so the build is
-#: O(n^2 x sweeps): on a v5e one 256-root batch at n = 131,769 took
-#: 18.3 s (752 sweeps of 24 ms), i.e. about 9,450 s for the whole
-#: build, and at n = 262,144 a sweep alone took 57 ms. 160 x 160 keeps
-#: the cold run well inside the 1200 s budget.
-ONE_CHIP_SIDE = 160
+#: shortest-path tree with dense Bellman-Ford sweeps over all n
+#: vertices, and a tree needs more sweeps the wider the graph, so the
+#: build grows faster than n^2. The side is the largest whose cold run
+#: on a v5e is predicted to stay well inside ten minutes (PERF.md
+#: section 5).
+ONE_CHIP_SIDE = 200
 ONE_CHIP_BATCH = 256
-#: Four-chip graph side: the Hybrid's DGLL supersteps all-gather dense
-#: [T, n] emission planes, so the distributed smoke stays small.
+#: Four-chip graph side. The Hybrid's dense DGLL superstep answers its
+#: cleaning queries with `labels.cover_best_rank`, which materializes a
+#: [q*T, n, cap] cube: compiled for a described v5e:2x2, the widest
+#: superstep needs 9.8 GB of temporaries at 64 x 64 and overflows a
+#: chip's 16 GiB at 72 x 72, and at 200 x 200 the TPU compiler refuses
+#: it (an operand of more than 2^31 words), as it did on four v5e
+#: chips (PERF.md section 5).
 FOUR_CHIP_SIDE = 64
 FOUR_CHIP_BATCH = 64
 PAIR_SOURCES = 64          # Dijkstra sources behind the random pairs
@@ -125,9 +131,9 @@ def run_one_chip(side: int = ONE_CHIP_SIDE, batch: int = ONE_CHIP_BATCH,
         f"max_deg={g.max_deg_in}; betweenness rank (16 samples) "
         f"{time.perf_counter() - t0:.1f}s")
     if side != 512:
-        say("graph: cut from 512x512 (n=262144): a full PLaNT build "
-            "at n=131769 measured 18.3 s per 256-root batch on a v5e "
-            "(about 9450 s in all)")
+        say("graph: cut from 512x512 (n=262144) so that the PLaNT "
+            "build (wall time below) keeps the cold run near ten "
+            "minutes")
 
     plan = BuildPlan(algo="plant", batch=batch)
     say(f"build: {sweep_form(g.n)}")
@@ -223,6 +229,9 @@ def run_four_chips(side: int = FOUR_CHIP_SIDE,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--side", type=int, default=None,
+                    help="grid_road side (default %d on one chip, %d "
+                    "on four)" % (ONE_CHIP_SIDE, FOUR_CHIP_SIDE))
     args = ap.parse_args(argv)
 
     forced = {k: os.environ[k] for k in KERNEL_ENV_VARS
@@ -245,9 +254,12 @@ def main(argv=None) -> int:
         f"count={len(jax.devices())}")
     say(f"compile cache: {enable_compile_cache()}")
 
-    bad = run_four_chips() if args.chips == 4 else run_one_chip()
-    stats = dev.memory_stats() or {}
-    say(f"memory: peak_bytes_in_use={stats.get('peak_bytes_in_use')}")
+    run = run_four_chips if args.chips == 4 else run_one_chip
+    bad = run() if args.side is None else run(args.side)
+    for d in jax.devices():
+        stats = d.memory_stats() or {}
+        say(f"memory: {d} peak_bytes_in_use="
+            f"{stats.get('peak_bytes_in_use')}")
     if bad:
         print(f"chip_smoke: {bad} answers differ from Dijkstra",
               file=sys.stderr)

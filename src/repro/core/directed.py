@@ -9,8 +9,10 @@ criterion applies per direction.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.core import labels as lbl
@@ -35,12 +37,14 @@ def plant_directed_chl(g, rank: np.ndarray, *, batch: int = 16,
     return res.sink.table("out"), res.sink.table("in")
 
 
+@functools.partial(jax.jit, static_argnames=("with_hub",))
 def query_directed(l_out: LabelTable, l_in: LabelTable, u, v, *,
                    with_hub: bool = False):
     """min over common hubs of d(u→x) + d(x→v).
 
     ``with_hub=True`` also returns the witnessing hub id per query
-    (-1 when the label sets are disjoint)."""
+    (-1 when the label sets are disjoint). Jitted, so the ``[Q, L, L]``
+    candidate cube of `intersect_rows` fuses into its reduction."""
     best, hub = lbl.intersect_rows(l_out.hubs[u], l_out.dist[u],
                                    l_in.hubs[v], l_in.dist[v])
     return (best, hub) if with_hub else best

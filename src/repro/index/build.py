@@ -95,21 +95,9 @@ def build(g, rank: np.ndarray, plan: Optional[BuildPlan] = None, *,
         streaming_shards = _resolve_shards(plan)
     notes = []
     if plan.algo != "pll-ref":           # the host oracle runs no sweeps
-        from repro.kernels.ell_relax import (kernel_fits,
-                                             resolve_use_kernel,
-                                             sweep_form,
-                                             vmem_fallback_note,
-                                             windowed_note)
-        notes.append(sweep_form(n))
-        if resolve_use_kernel(None) and not kernel_fits(n):
-            # surface the windowing decision in the report: single-host
-            # builds stream the source-windowed kernel; the distributed
-            # policies pass traced adjacency into shard_map supersteps
-            # and still fall back to the jnp reference there
-            if plan.algo in ("dgll", "hybrid", "plant-dist"):
-                notes.append(vmem_fallback_note(n))
-            else:
-                notes.append(windowed_note(n))
+        from repro.kernels.ell_relax import sweep_form
+        notes.append(sweep_form(n, distributed=plan.algo in (
+            "dgll", "hybrid", "plant-dist")))
     overflow_events = []
     t0 = time.perf_counter()
     attempt = 0

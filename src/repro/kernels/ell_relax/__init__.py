@@ -8,8 +8,7 @@ from repro.kernels.ell_relax.layout import (DEFAULT_VMEM_BUDGET,
 from repro.kernels.ell_relax.ops import (ELL_RELAX_ENV_VAR, ell_sweep,
                                          reset_warnings, resolve_sweep_backend,
                                          resolve_use_kernel, sweep_form,
-                                         vmem_fallback_note,
-                                         warn_vmem_fallback, windowed_note)
+                                         warn_vmem_fallback)
 from repro.kernels.ell_relax.ref import ell_sweep_bucketed_ref, ell_sweep_ref
 
 __all__ = [
@@ -20,6 +19,6 @@ __all__ = [
     "sweep_layout", "build_bucketed_ell", "clear_layout_cache",
     "BucketedEll", "WindowPlan",
     "ELL_RELAX_ENV_VAR", "VMEM_BUDGET_ENV_VAR", "DEFAULT_VMEM_BUDGET",
-    "windowed_note", "vmem_fallback_note", "warn_vmem_fallback",
+    "warn_vmem_fallback",
     "reset_warnings",
 ]

@@ -14,7 +14,8 @@ Backend selection (``use_kernel``):
 - ``None``  — auto: the jnp reference (the XLA form) on every
   platform. Compiled for a TPU, Mosaic refuses the kernel's in-VMEM
   gather, so the chip path is the XLA form by decision, not by a
-  runtime fallback. ``REPRO_ELL_RELAX=kernel`` selects the kernel
+  runtime fallback. ``REPRO_ELL_RELAX=kernel`` (its one non-default
+  value) selects the kernel
   (with ``REPRO_PALLAS_BACKEND=interpret`` it runs emulated
   end-to-end, as CI's bench smoke does).
 
@@ -72,16 +73,6 @@ def _warn_once(n: int, reason: str, message: str) -> None:
     warnings.warn(message, stacklevel=4)
 
 
-def windowed_note(n: int) -> str:
-    """Advisory for `BuildReport.notes`: this build's sweeps run the
-    source-windowed kernel — records the chosen window geometry."""
-    plan = window_plan(n)
-    return (f"ell_relax: n={n} exceeds the single-window VMEM budget "
-            f"({vmem_budget()} B); sweeps run the source-windowed "
-            f"kernel (window={plan.window}, "
-            f"num_windows={plan.num_windows}).")
-
-
 def vmem_fallback_note(n: int) -> str:
     return (f"ell_relax: n={n} exceeds the single-window VMEM budget "
             f"({vmem_budget()} B) and the adjacency is traced (no "
@@ -117,23 +108,31 @@ def resolve_use_kernel(use_kernel: bool | None = None) -> bool:
     mode = os.environ.get(ELL_RELAX_ENV_VAR, "auto").strip().lower()
     if mode == "kernel":
         return True
-    if mode not in ("", "auto", "ref"):
+    if mode not in ("", "auto"):
         raise ValueError(f"{ELL_RELAX_ENV_VAR}={mode!r}; expected "
-                         "auto, kernel, or ref")
+                         "auto or kernel")
     return False
 
 
-def sweep_form(n: int, use_kernel: bool | None = None) -> str:
+def sweep_form(n: int, use_kernel: bool | None = None, *,
+               distributed: bool = False) -> str:
     """One line naming the sweep form a build over ``n`` vertices runs
-    (for `BuildReport.notes` and launch logs)."""
+    (for `BuildReport.notes` and launch logs). ``distributed`` builds
+    pass traced adjacency into their shard_map supersteps, so past the
+    VMEM budget they run the jnp reference even when the kernel is
+    asked for."""
     if not resolve_use_kernel(use_kernel):
         return ("sweep: xla (ell_sweep_ref, one whole-plane gather; "
                 "windows=1) — the Pallas ell_relax kernel is not used: "
                 "Mosaic refuses its in-VMEM gather on TPU")
+    if kernel_fits(n):
+        return "sweep: pallas ell_relax kernel (dense; windows=1)"
+    if distributed:
+        return f"sweep: {vmem_fallback_note(n)}"
     plan = window_plan(n)
-    return (f"sweep: pallas ell_relax kernel "
-            f"({'source-windowed' if plan.num_windows > 1 else 'dense'}"
-            f"; windows={plan.num_windows})")
+    return (f"sweep: pallas ell_relax kernel (source-windowed; "
+            f"window={plan.window}, windows={plan.num_windows}) — n={n} "
+            f"exceeds the single-window VMEM budget ({vmem_budget()} B)")
 
 
 def resolve_sweep_backend(ell_src, ell_w, *,

@@ -234,8 +234,10 @@ def test_gated_fixpoint_with_cover_block_fn():
 def test_resolve_use_kernel_env(monkeypatch):
     monkeypatch.setenv(ELL_RELAX_ENV_VAR, "kernel")
     assert resolve_use_kernel(None) is True
+    # the XLA form is the default and has no second spelling
     monkeypatch.setenv(ELL_RELAX_ENV_VAR, "ref")
-    assert resolve_use_kernel(None) is False
+    with pytest.raises(ValueError):
+        resolve_use_kernel(None)
     # auto is the XLA form on every platform (Mosaic refuses the
     # kernel's gather for TPU), whatever the Pallas backend resolves to
     monkeypatch.setenv(ELL_RELAX_ENV_VAR, "auto")
@@ -266,7 +268,7 @@ def test_explicit_env_kernel_end_to_end(monkeypatch):
     from repro.core.plant import plant_chl
     g = grid_road(4, 4, seed=2)
     rank = degree_ranking(g)
-    monkeypatch.setenv(ELL_RELAX_ENV_VAR, "ref")
+    monkeypatch.setenv(ELL_RELAX_ENV_VAR, "auto")
     jax.clear_caches()
     t_ref, _ = plant_chl(g, rank, batch=8)
     monkeypatch.setenv(ELL_RELAX_ENV_VAR, "kernel")
@@ -482,7 +484,7 @@ def test_engine_layout_threading_survives_jit(monkeypatch):
     g = scale_free(300, attach=2, seed=2)
     rank = degree_ranking(g)
     order = np.argsort(-rank.astype(np.int64))[:32].astype(np.int64)
-    monkeypatch.setenv(ELL_RELAX_ENV_VAR, "ref")
+    monkeypatch.setenv(ELL_RELAX_ENV_VAR, "auto")
     jax.clear_caches()
     t_ref, _ = plant_chl(g, rank, batch=32, roots_order=order)
     monkeypatch.setenv(ELL_RELAX_ENV_VAR, "kernel")

@@ -8,10 +8,12 @@ the chip path runs, at deployment widths:
 
 - (a) the relaxation sweep (`plant_batch`, XLA form) at the one-chip
   smoke's n and road degree, and the label-table insert behind it;
-- (b) the jitted store query and the QLSN answer fn at L = 2088 (the
-  default cap at NY road scale, n = 264,346) and Q = 1024, whose
-  temporaries plus arguments must fit one chip's 16 GiB;
-- (c) one distributed PLaNT superstep on a four-device mesh built from
+- (b) the jitted store and directed queries and the QLSN answer fn at
+  L = 2088 (the default cap at NY road scale, n = 264,346) and
+  Q = 1024, whose temporaries plus arguments must fit one chip's
+  16 GiB;
+- (c) one distributed PLaNT superstep, and the widest dense DGLL
+  superstep of the four-chip smoke, on a four-device mesh built from
   the topology's devices.
 
 The topology is described inside a module-scoped fixture — never at
@@ -35,7 +37,8 @@ from repro.core import labels as lbl
 from repro.core.labels import LabelTable
 
 GiB = 1024 ** 3
-SMOKE_N = 160 * 160           # chip_smoke.py's one-chip graph
+SMOKE_N = 200 * 200           # chip_smoke.py's one-chip graph
+FOUR_CHIP_N = 64 * 64         # chip_smoke.py's four-chip graph
 ROAD_DEG = 8                  # grid_road's ELL width (degree ≤ 6, padded)
 NY_CAP = lbl.default_cap(264_346)
 
@@ -130,6 +133,19 @@ def test_store_query_fits_one_chip(one_chip, n, cap, Q):
     assert used < 4 * n * cap * 4 + 8 * Q * cap * 4
 
 
+def test_directed_query_fits_one_chip(one_chip):
+    """The directed query (hub witness included) fuses its cube too."""
+    from repro.core.directed import query_directed
+    n, cap, Q = 264_346, NY_CAP, 1024
+    compiled = query_directed.lower(_table_spec(n, cap, one_chip),
+                                    _table_spec(n, cap, one_chip),
+                                    _spec((Q,), jnp.int32, one_chip),
+                                    _spec((Q,), jnp.int32, one_chip),
+                                    with_hub=True).compile()
+    used = _fits(compiled)
+    assert used < 2 * 4 * n * cap * 4 + 8 * Q * cap * 4
+
+
 def test_qlsn_answer_fn_fits_one_chip(one_chip):
     from repro.core.query import qlsn
     n, cap, Q = 264_346, NY_CAP, 1024
@@ -161,6 +177,40 @@ def test_distributed_plant_superstep_compiles_for_v5e_2x2(topo, on_tpu):
     # PLaNT supersteps need no communication (§5.2)
     for op in ("all-gather", "all-reduce", "collective-permute"):
         assert op not in hlo, op
+
+
+def test_distributed_dgll_superstep_fits_v5e_2x2(topo, on_tpu):
+    """The Hybrid's dense DGLL superstep at the four-chip smoke's size,
+    at the widest superstep its schedule reaches: its cleaning cube
+    [q*T, n, cap] sets how large that smoke can be."""
+    from repro.compat import make_mesh
+    from repro.core.dgll import assign_roots, dgll_superstep_fn
+    from repro.engine.scheduler import QueueSchedule
+    mesh = make_mesh((4,), ("node",), devices=topo.devices)
+    node = NamedSharding(mesh, P("node"))
+    rep = NamedSharding(mesh, P())
+    n, batch = FOUR_CHIP_N, 64
+    cap = lbl.default_cap(n)
+    queues = assign_roots(np.arange(n), 4)
+    # BuildPlan's defaults: beta 8, and 16 common-table hubs, whose
+    # prologue takes the first 16 / 4 columns
+    T = max(st.roots.shape[1] for st in
+            QueueSchedule(queues, batch, beta=8.0).steps(start=4))
+    fn = dgll_superstep_fn(mesh, n, batch=batch, use_hc=True,
+                           plant_trees=False)
+    compiled = fn.lower(
+        _table_spec(n, cap, node, lead=(4,)),
+        _table_spec(n, 64, rep),
+        _spec((n,), jnp.int32, rep),
+        _spec((4, T), jnp.int32, node),
+        _spec((4, T), jnp.bool_, node),
+        _spec((n, ROAD_DEG), jnp.int32, rep),
+        _spec((n, ROAD_DEG), jnp.float32, rep)).compile()
+    _fits(compiled)
+    # DGLL supersteps broadcast their trees and all-reduce the verdicts
+    hlo = compiled.as_text()
+    for op in ("all-gather", "all-reduce"):
+        assert op in hlo, op
 
 
 def test_qfdl_answer_fn_compiles_for_v5e_2x2(topo):
