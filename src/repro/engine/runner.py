@@ -27,6 +27,7 @@ from __future__ import annotations
 
 from typing import Dict, List, NamedTuple, Optional
 
+import jax
 import numpy as np
 
 from repro.core.labels import LabelOverflowError
@@ -210,7 +211,9 @@ def run(policy: Policy, sink, *, ckpt=None, resume: bool = False,
             commit(out, pos, size)
 
     for st in schedule.steps(start=pos, size=size):
-        out = policy.step(st, sink)
+        with jax.profiler.StepTraceAnnotation("engine.superstep",
+                                              step_num=st.pos):
+            out = policy.step(st, sink)
         if out is not None:
             commit(out, st.end, st.next_size)
 
@@ -273,43 +276,46 @@ def run_build(g, rank: np.ndarray, *, algo: str, batch: int = 8,
             f"(algo={algo!r} needs its dense global table during "
             "construction)")
 
-    if algo in ("dgll", "hybrid", "plant-dist"):
-        from repro.core.dgll import make_node_mesh
-        from repro.engine.dist import DistributedPolicy
-        mesh = mesh or make_node_mesh()
-        if algo == "plant-dist":
-            eta, psi_threshold = 0, float("inf")
-        elif algo == "dgll":
-            psi_threshold = 0.0
-        policy = DistributedPolicy(
-            g, rank, mesh=mesh, batch=batch, beta=beta,
-            first_superstep=first_superstep, cap=cap, eta=eta,
-            hc_cap=hc_cap, psi_threshold=psi_threshold, compact=compact,
-            mode_name=algo, verbose=verbose)
-        sink = MeshTableSink(mesh, n, cap)
-    elif algo == "plant":
-        policy = PlantPolicy(g, rank, batch=batch, hc=hc,
-                             roots_order=roots_order)
-        sink = (StreamingShardSink(n, rank, streaming_shards)
-                if streaming_shards else DenseSink(n, cap))
-    elif algo == "directed":
-        policy = DirectedPlantPolicy(g, rank, batch=batch)
-        sink = DenseSink(n, cap, channels=("out", "in"))
-    elif algo == "pll-ref":
-        policy = PLLRefPolicy(g, rank, batch=batch)
-        sink = (StreamingShardSink(n, rank, streaming_shards)
-                if streaming_shards else DenseSink(n, cap))
-    elif algo in ("gll", "lcc", "parapll"):
-        if algo == "lcc":
-            alpha = None
-        elif algo == "parapll":
-            alpha, rank_queries, clean = None, False, False
-        policy = GLLPolicy(g, rank, batch=batch, cap=cap, alpha=alpha,
-                           rank_queries=rank_queries, clean=clean,
-                           plant_first_superstep=plant_first_superstep,
-                           mode_name=algo)
-        sink = DenseSink(n, cap)
-    else:
-        raise ValueError(f"unhandled algo {algo!r}")
+    # the policy's and sink's device set-up: adjacency upload, layout,
+    # fingerprint, empty label tables
+    with jax.profiler.TraceAnnotation("engine.init"):
+        if algo in ("dgll", "hybrid", "plant-dist"):
+            from repro.core.dgll import make_node_mesh
+            from repro.engine.dist import DistributedPolicy
+            mesh = mesh or make_node_mesh()
+            if algo == "plant-dist":
+                eta, psi_threshold = 0, float("inf")
+            elif algo == "dgll":
+                psi_threshold = 0.0
+            policy = DistributedPolicy(
+                g, rank, mesh=mesh, batch=batch, beta=beta,
+                first_superstep=first_superstep, cap=cap, eta=eta,
+                hc_cap=hc_cap, psi_threshold=psi_threshold, compact=compact,
+                mode_name=algo, verbose=verbose)
+            sink = MeshTableSink(mesh, n, cap)
+        elif algo == "plant":
+            policy = PlantPolicy(g, rank, batch=batch, hc=hc,
+                                 roots_order=roots_order)
+            sink = (StreamingShardSink(n, rank, streaming_shards)
+                    if streaming_shards else DenseSink(n, cap))
+        elif algo == "directed":
+            policy = DirectedPlantPolicy(g, rank, batch=batch)
+            sink = DenseSink(n, cap, channels=("out", "in"))
+        elif algo == "pll-ref":
+            policy = PLLRefPolicy(g, rank, batch=batch)
+            sink = (StreamingShardSink(n, rank, streaming_shards)
+                    if streaming_shards else DenseSink(n, cap))
+        elif algo in ("gll", "lcc", "parapll"):
+            if algo == "lcc":
+                alpha = None
+            elif algo == "parapll":
+                alpha, rank_queries, clean = None, False, False
+            policy = GLLPolicy(g, rank, batch=batch, cap=cap, alpha=alpha,
+                               rank_queries=rank_queries, clean=clean,
+                               plant_first_superstep=plant_first_superstep,
+                               mode_name=algo)
+            sink = DenseSink(n, cap)
+        else:
+            raise ValueError(f"unhandled algo {algo!r}")
 
     return run(policy, sink, ckpt=ckpt, resume=resume, verbose=verbose)

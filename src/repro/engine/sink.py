@@ -69,9 +69,10 @@ class DenseSink:
     def insert(self, roots: Array, emit: Array, dist: Array,
                channel: Optional[str] = None) -> None:
         ch = channel or self.channels[0]
-        self.tables[ch], ovf = lbl.insert_batch(
-            self.tables[ch], roots, emit, dist)
-        self._ovf = self._ovf | ovf
+        with jax.profiler.TraceAnnotation("sink.insert"):
+            self.tables[ch], ovf = lbl.insert_batch(
+                self.tables[ch], roots, emit, dist)
+            self._ovf = self._ovf | ovf
 
     def note_overflow(self, flag: Array) -> None:
         """Fold in an overflow verdict from outside the sink (e.g. a
@@ -134,11 +135,12 @@ class StreamingShardSink:
                channel: Optional[str] = None,
                valid: Optional[Array] = None) -> None:
         assert channel in (None, "labels")
-        roots_h = np.asarray(roots)
-        valid_h = (np.ones(len(roots_h), bool) if valid is None
-                   else np.asarray(valid))
-        self.acc.insert(roots_h, valid_h, np.asarray(emit),
-                        np.asarray(dist))
+        with jax.profiler.TraceAnnotation("sink.insert"):
+            roots_h = np.asarray(roots)
+            valid_h = (np.ones(len(roots_h), bool) if valid is None
+                       else np.asarray(valid))
+            self.acc.insert(roots_h, valid_h, np.asarray(emit),
+                            np.asarray(dist))
 
     def note_overflow(self, flag) -> None:      # pragma: no cover
         del flag                       # shard caps regrow; nothing to do

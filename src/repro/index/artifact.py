@@ -183,16 +183,17 @@ class CHLIndex:
 
     def query_with_hub(self, u, v) -> Tuple[np.ndarray, np.ndarray]:
         """Distances plus the witnessing hub id (-1 when disjoint)."""
-        if self.directed:
-            from repro.core.directed import query_directed
-            u = jnp.atleast_1d(jnp.asarray(u, jnp.int32))
-            v = jnp.atleast_1d(jnp.asarray(v, jnp.int32))
-            d, h = query_directed(self.l_out, self.l_in, u, v,
-                                  with_hub=True)
-            return np.asarray(d), np.asarray(h)
-        # each store normalizes its own inputs (a spill store runs in
-        # host numpy — don't bounce its queries through the device)
-        return self.store.query(u, v)
+        with jax.profiler.TraceAnnotation("index.query"):
+            if self.directed:
+                from repro.core.directed import query_directed
+                u = jnp.atleast_1d(jnp.asarray(u, jnp.int32))
+                v = jnp.atleast_1d(jnp.asarray(v, jnp.int32))
+                d, h = query_directed(self.l_out, self.l_in, u, v,
+                                      with_hub=True)
+                return np.asarray(d), np.asarray(h)
+            # each store normalizes its own inputs (a spill store runs in
+            # host numpy — don't bounce its queries through the device)
+            return self.store.query(u, v)
 
     # --------------------------------------------------------- serve
 

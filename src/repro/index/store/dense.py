@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from typing import Dict, Iterator, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -37,10 +38,12 @@ class DenseStore:
         return lbl.total_labels(self._table)
 
     def query(self, u, v) -> Tuple[np.ndarray, np.ndarray]:
-        u = jnp.atleast_1d(jnp.asarray(u, jnp.int32))
-        v = jnp.atleast_1d(jnp.asarray(v, jnp.int32))
+        with jax.profiler.TraceAnnotation("store.put"):
+            u = jnp.atleast_1d(jnp.asarray(u, jnp.int32))
+            v = jnp.atleast_1d(jnp.asarray(v, jnp.int32))
         d, h = lbl.query_pairs(self._table, u, v)
-        return np.asarray(d), np.asarray(h)
+        with jax.profiler.TraceAnnotation("store.fetch"):
+            return np.asarray(d), np.asarray(h)
 
     def shard_counts(self) -> np.ndarray:
         """``[1, n]`` label counts (routing degenerates for one shard)."""

@@ -7,7 +7,9 @@ contract (``queries`` / ``batches`` / ``busy_s`` / ``warmup_s`` /
 service-tier signals: queue depth, admission rejections, batch
 occupancy (real queries vs launched kernel slots — the zero-pad
 waste), cache hit rate, and per-stage latency samples (queue wait,
-kernel answer, submit→done total).
+answer call, submit→done total). Every time here is host time: the
+answer call's seconds hold the upload of the pairs and the fetch of
+the answers, not the device's time alone.
 
 Percentiles over *no* samples report ``nan``, never a fabricated 0.0:
 an empty run must be visibly empty, so it can be skipped rather than
@@ -50,7 +52,7 @@ class ServiceStats:
     # legacy accounting (the pre-service QueryServer contract)
     queries: int = 0               # answered queries (cache hits incl.)
     batches: int = 0               # kernel launches
-    busy_s: float = 0.0            # measured kernel seconds
+    busy_s: float = 0.0            # host seconds in the answer call
     warmup_s: float = 0.0          # compile/first-batch time, kept apart
     measured_queries: int = 0      # launched queries behind busy_s
 
@@ -99,15 +101,18 @@ class ServiceStats:
 
     @property
     def throughput_qps(self) -> float:
-        """Kernel-side throughput over the measured queries only — a
-        warmup batch contributes neither time nor count, so a
-        single-batch caller reports 0 rather than N/epsilon."""
+        """Measured queries over ``busy_s``: host seconds around each
+        answer call, the upload of the pairs and the fetch of the
+        answers included, not device time. A warmup batch contributes
+        neither time nor count, so a single-batch caller reports 0
+        rather than N/epsilon."""
         return self.measured_queries / max(self.busy_s, 1e-9)
 
     @property
     def capacity_qps(self) -> float:
         """Service capacity including cache absorption: answered
-        queries (hits + launched) per measured kernel second."""
+        queries (hits + launched) per measured answer-call second
+        (``busy_s``, host time)."""
         return ((self.measured_queries + self.cache_hits)
                 / max(self.busy_s, 1e-9))
 
