@@ -121,6 +121,7 @@ def run_one_chip(side: int = ONE_CHIP_SIDE, batch: int = ONE_CHIP_BATCH,
     from repro.index import BuildPlan, CHLIndex, build
     from repro.kernels.ell_relax import sweep_form
     from repro.sssp.oracle import dijkstra
+    from repro.sssp.relax import ell_layout
 
     rng = np.random.default_rng(seed)
 
@@ -136,7 +137,7 @@ def run_one_chip(side: int = ONE_CHIP_SIDE, batch: int = ONE_CHIP_BATCH,
             "minutes")
 
     plan = BuildPlan(algo="plant", batch=batch)
-    say(f"build: {sweep_form(g.n)}")
+    say(f"build: {sweep_form(g.n, layout=ell_layout(g.ell_src, g.ell_w))}")
     timer = CompileTimer()
     jax.monitoring.register_event_duration_secs_listener(timer)
     t0 = time.perf_counter()

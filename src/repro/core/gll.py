@@ -59,10 +59,11 @@ def construct_batch(ell_src: Array, ell_w: Array, rank: Array,
     Blocking = [rank query] ∨ distance query vs (global ∪ local)
     committed tables; emission = reached ∧ unblocked at fixpoint.
 
-    ``layout``: optional precomputed source-bucketed ELL layout
-    (`repro.sssp.relax.ell_layout`, a pytree) — keeps the fused kernel
-    past the single-window VMEM budget; without it the traced
-    adjacency forces the jnp-reference sweep there.
+    ``layout``: optional precomputed sweep layout
+    (`repro.sssp.relax.ell_layout`, a pytree) — the in-degree width
+    classes of the XLA sweep, or the source-bucketed ELL that keeps
+    the fused kernel past the single-window VMEM budget; without it
+    the sweep reads the dense (traced) ELL.
     """
     hmap_g = lbl.hub_distance_map(glob, roots)
     hmap_l = lbl.hub_distance_map(loc, roots)

@@ -48,12 +48,13 @@ def plant_batch(ell_src: Array, ell_w: Array, rank: Array, roots: Array,
     top-η hubs, used as a distance-query pruning oracle for PLaNTed
     trees.
 
-    ``layout``: optional precomputed source-bucketed ELL layout
-    (`repro.sssp.relax.ell_layout`) — required to keep the fused
-    kernel past the single-window VMEM budget, since the adjacency is
-    a tracer in here and cannot be bucketed on the fly. A
-    `BucketedEll` is a pytree, so it threads through this jit like any
-    other operand.
+    ``layout``: optional precomputed sweep layout
+    (`repro.sssp.relax.ell_layout`), built outside because the
+    adjacency is a tracer in here: the in-degree width classes the XLA
+    sweep gathers through (`DegreeBuckets`), or the source-bucketed
+    ELL that keeps the fused kernel past the single-window VMEM budget
+    (`BucketedEll`). Both are pytrees, so they thread through this jit
+    like any other operand.
     """
     if use_hc:
         assert hc is not None

@@ -66,7 +66,7 @@ def build_common_table(g, rank: np.ndarray, eta_roots: np.ndarray,
     ew = jnp.asarray(g.ell_w)
     tb = plant_batch(es, ew,
                      jnp.asarray(np.asarray(rank).astype(np.int32)),
-                     roots, valid, layout=ell_layout(es, ew))
+                     roots, valid, layout=ell_layout(g.ell_src, g.ell_w))
     hc, ovf = lbl.insert_batch(hc, roots, tb.emit, tb.dist)
     if bool(ovf):
         raise lbl.LabelOverflowError(hc_cap, "common label table")

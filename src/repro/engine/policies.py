@@ -140,10 +140,10 @@ class PlantPolicy(Policy):
                       else rank_order(rank))
         self.ell_src = jnp.asarray(g.ell_src)
         self.ell_w = jnp.asarray(g.ell_w)
-        # bucketed layout (None when one VMEM window covers the graph):
-        # built eagerly here because inside the jitted plant_batch the
-        # adjacency is a tracer and cannot be bucketed
-        self.layout = ell_layout(self.ell_src, self.ell_w)
+        # the sweep's layout (in-degree width classes for the XLA
+        # form): built eagerly here, from the host arrays, because
+        # inside the jitted plant_batch the adjacency is a tracer
+        self.layout = ell_layout(g.ell_src, g.ell_w)
         self.rank_d = jnp.asarray(np.asarray(rank).astype(np.int32))
         self.hc = hc
         self.fingerprint = build_fingerprint(g, rank)
@@ -200,8 +200,8 @@ class DirectedPlantPolicy(Policy):
         self.order = rank_order(rank)
         self.fwd = (jnp.asarray(g.ell_src), jnp.asarray(g.ell_w))
         self.bwd = (jnp.asarray(gr.ell_src), jnp.asarray(gr.ell_w))
-        self.fwd_layout = ell_layout(*self.fwd)
-        self.bwd_layout = ell_layout(*self.bwd)
+        self.fwd_layout = ell_layout(g.ell_src, g.ell_w)
+        self.bwd_layout = ell_layout(gr.ell_src, gr.ell_w)
         self.rank_d = jnp.asarray(np.asarray(rank).astype(np.int32))
         self.fingerprint = build_fingerprint(g, rank)
 
@@ -256,7 +256,7 @@ class GLLPolicy(Policy):
         self.order = rank_order(rank)
         self.ell_src = jnp.asarray(g.ell_src)
         self.ell_w = jnp.asarray(g.ell_w)
-        self.layout = ell_layout(self.ell_src, self.ell_w)
+        self.layout = ell_layout(g.ell_src, g.ell_w)
         self.rank_d = jnp.asarray(np.asarray(rank).astype(np.int32))
         self.alpha = alpha
         self.rank_queries = rank_queries

@@ -96,8 +96,10 @@ def build(g, rank: np.ndarray, plan: Optional[BuildPlan] = None, *,
     notes = []
     if plan.algo != "pll-ref":           # the host oracle runs no sweeps
         from repro.kernels.ell_relax import sweep_form
-        notes.append(sweep_form(n, distributed=plan.algo in (
-            "dgll", "hybrid", "plant-dist")))
+        from repro.sssp.relax import ell_layout
+        distributed = plan.algo in ("dgll", "hybrid", "plant-dist")
+        notes.append(sweep_form(n, distributed=distributed, layout=(
+            None if distributed else ell_layout(g.ell_src, g.ell_w))))
     overflow_events = []
     t0 = time.perf_counter()
     attempt = 0

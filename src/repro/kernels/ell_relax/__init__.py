@@ -1,3 +1,5 @@
+from repro.kernels.ell_relax.degree import (DegreeBuckets, degree_buckets,
+                                            sweep_degree)
 from repro.kernels.ell_relax.ell_relax import ell_relax, ell_relax_windowed
 from repro.kernels.ell_relax.layout import (DEFAULT_VMEM_BUDGET,
                                             VMEM_BUDGET_ENV_VAR, BucketedEll,
@@ -18,6 +20,7 @@ __all__ = [
     "kernel_fits", "max_window", "vmem_budget", "window_plan",
     "sweep_layout", "build_bucketed_ell", "clear_layout_cache",
     "BucketedEll", "WindowPlan",
+    "DegreeBuckets", "degree_buckets", "sweep_degree",
     "ELL_RELAX_ENV_VAR", "VMEM_BUDGET_ENV_VAR", "DEFAULT_VMEM_BUDGET",
     "warn_vmem_fallback",
     "reset_warnings",

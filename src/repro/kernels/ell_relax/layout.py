@@ -251,6 +251,8 @@ def _host(x) -> Optional[np.ndarray]:
 
 
 _CACHE_MAX = 4
+#: (kind, id(ell_src), id(ell_w)) -> (weakref, weakref, layout), newest
+#: last; shared by every layout builder of the sweep
 _cache: "OrderedDict[tuple, tuple]" = OrderedDict()
 
 
@@ -258,22 +260,13 @@ def clear_layout_cache() -> None:
     _cache.clear()
 
 
-def sweep_layout(ell_src, ell_w, *, bb: int = 8, bn: int = 128,
-                 max_window: Optional[int] = None,
-                 dk_max: int = 128) -> Optional[BucketedEll]:
-    """The one layout entry point: bucketed layout for this adjacency,
-    or None when a single window fits (dense fast path) or the inputs
-    are traced (caller falls back to the reference).
-
-    Cached by adjacency identity (id-keyed, weakref-validated, small
-    LRU) — drivers and policies can call it eagerly once per graph and
-    repeated sweeps hit the cache.
-    """
-    plan = window_plan(int(ell_src.shape[0]), bb=bb, bn=bn,
-                       max_window=max_window)
-    if plan.num_windows <= 1:
-        return None
-    key = (id(ell_src), id(ell_w), plan, bn, dk_max)
+def cached_layout(kind, ell_src, ell_w, build):
+    """``build(host_src, host_w)`` for this adjacency, cached by the
+    arrays' identity under ``kind`` (id-keyed, weakref-validated, small
+    LRU), so drivers and policies can call it eagerly once per graph
+    and repeated sweeps hit the cache; None when the inputs are traced
+    (inside a jit the adjacency cannot be read on the host)."""
+    key = (kind, id(ell_src), id(ell_w))
     hit = _cache.get(key)
     if hit is not None:
         ref_s, ref_w, layout = hit
@@ -284,7 +277,7 @@ def sweep_layout(ell_src, ell_w, *, bb: int = 8, bn: int = 128,
     hs, hw = _host(ell_src), _host(ell_w)
     if hs is None or hw is None:
         return None
-    layout = build_bucketed_ell(hs, hw, plan, bn=bn, dk_max=dk_max)
+    layout = build(hs, hw)
     try:
         _cache[key] = (weakref.ref(ell_src), weakref.ref(ell_w), layout)
         while len(_cache) > _CACHE_MAX:
@@ -292,3 +285,21 @@ def sweep_layout(ell_src, ell_w, *, bb: int = 8, bn: int = 128,
     except TypeError:
         pass                 # plain numpy inputs aren't weakref-able
     return layout
+
+
+def sweep_layout(ell_src, ell_w, *, bb: int = 8, bn: int = 128,
+                 max_window: Optional[int] = None,
+                 dk_max: int = 128) -> Optional[BucketedEll]:
+    """The one layout entry point: bucketed layout for this adjacency,
+    or None when a single window fits (dense fast path) or the inputs
+    are traced (caller falls back to the reference). Cached per graph
+    (`cached_layout`).
+    """
+    plan = window_plan(int(ell_src.shape[0]), bb=bb, bn=bn,
+                       max_window=max_window)
+    if plan.num_windows <= 1:
+        return None
+    return cached_layout(
+        ("bucketed", plan, bn, dk_max), ell_src, ell_w,
+        lambda hs, hw: build_bucketed_ell(hs, hw, plan, bn=bn,
+                                          dk_max=dk_max))

@@ -50,6 +50,7 @@ from repro.kernels.ell_relax.layout import (  # noqa: F401 — re-exported
     DEFAULT_VMEM_BUDGET, VMEM_BUDGET_ENV_VAR, BucketedEll, WindowPlan,
     build_bucketed_ell, clear_layout_cache, kernel_fits, max_window,
     sweep_layout, vmem_budget, window_plan)
+from repro.kernels.ell_relax.degree import DegreeBuckets
 from repro.kernels.ell_relax.ref import ell_sweep_ref
 
 ELL_RELAX_ENV_VAR = "REPRO_ELL_RELAX"
@@ -115,16 +116,23 @@ def resolve_use_kernel(use_kernel: bool | None = None) -> bool:
 
 
 def sweep_form(n: int, use_kernel: bool | None = None, *,
-               distributed: bool = False) -> str:
+               distributed: bool = False, layout=None) -> str:
     """One line naming the sweep form a build over ``n`` vertices runs
     (for `BuildReport.notes` and launch logs). ``distributed`` builds
-    pass traced adjacency into their shard_map supersteps, so past the
-    VMEM budget they run the jnp reference even when the kernel is
-    asked for."""
+    pass traced adjacency into their shard_map supersteps, so they run
+    the XLA form over the dense ELL, and past the VMEM budget the jnp
+    reference even when the kernel is asked for. ``layout`` is the
+    graph's `DegreeBuckets` (from `repro.sssp.relax.ell_layout`) where
+    the XLA form gathers through its width classes: a single-host
+    build's."""
     if not resolve_use_kernel(use_kernel):
-        return ("sweep: xla (ell_sweep_ref, one whole-plane gather; "
-                "windows=1) — the Pallas ell_relax kernel is not used: "
-                "Mosaic refuses its in-VMEM gather on TPU")
+        if isinstance(layout, DegreeBuckets):
+            how = f"over {layout.describe()}"
+        else:
+            how = "over the dense ELL" + (
+                ", traced inside shard_map" if distributed else "")
+        return (f"sweep: xla ({how}) — the Pallas ell_relax kernel is "
+                "not used: Mosaic refuses its in-VMEM gather on TPU")
     if kernel_fits(n):
         return "sweep: pallas ell_relax kernel (dense; windows=1)"
     if distributed:

@@ -28,12 +28,23 @@ shortest path the chain of vertices unblocks inductively from the root
 
 Execution model (this is the single hottest path in the repo):
 
-- each sweep runs through ``repro.kernels.ell_relax.ell_sweep`` — the
-  jnp reference compiled by XLA on every platform (Mosaic refuses the
-  fused Pallas kernel's gather for TPU), or the bit-identical fused
-  Pallas ELL (min,+,max-rank) kernel when asked for (``use_kernel`` /
-  ``REPRO_ELL_RELAX=kernel``; `REPRO_PALLAS_BACKEND` picks the Pallas
-  execution mode underneath);
+- each sweep is the jnp reference (`ell_sweep_ref`) compiled by XLA
+  on every platform (Mosaic refuses the fused Pallas kernel's gather
+  for TPU), or the bit-identical fused Pallas ELL (min,+,max-rank)
+  kernel when asked for (``use_kernel`` / ``REPRO_ELL_RELAX=kernel``;
+  `REPRO_PALLAS_BACKEND` picks the Pallas execution mode underneath);
+- the XLA form gathers through the graph's in-degree width classes
+  (`DegreeBuckets`, built by `ell_layout` and threaded in through
+  ``layout=``): its gathers, adds and reductions scale with the slots
+  of the classes — the arcs, rounded up per class — instead of n
+  times the widest row, with one gather per class
+  (`repro.kernels.ell_relax.degree`). The driver moves ``rank``,
+  ``roots`` and the initial planes into the layout's vertex order
+  once, runs the fixpoint loop there with no per-sweep permutation
+  (a ``block_fn`` is applied through the permutation), and moves
+  ``dist``/``mrank`` back once. Without a
+  layout (traced adjacency, as in the distributed shard_map
+  supersteps) it sweeps the dense ELL;
 - sweeps are **frontier-gated** (default on the kernel path): only
   vertices whose (dist, mrank) changed last sweep — plus vertices
   that just *unblocked*, whose pending contribution was masked while
@@ -60,36 +71,46 @@ Execution model (this is the single hottest path in the repo):
 
 from __future__ import annotations
 
-from typing import Callable, NamedTuple, Optional
+from typing import Callable, NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.ell_relax import (BucketedEll, ell_sweep,
+from repro.kernels.ell_relax import (BucketedEll, DegreeBuckets,
+                                     degree_buckets, ell_sweep,
                                      resolve_sweep_backend,
-                                     resolve_use_kernel, sweep_layout)
+                                     resolve_use_kernel, sweep_degree,
+                                     sweep_layout)
 
 Array = jax.Array
 BlockFn = Callable[[Array, Array], Array]   # (dist [B,n], roots [B]) -> blocked [B,n]
+Layout = Union[BucketedEll, DegreeBuckets]
 
 DEFAULT_CHECK_EVERY = 4
 
 
 def ell_layout(ell_src: Array, ell_w: Array, *,
-               max_window: Optional[int] = None) -> Optional[BucketedEll]:
-    """Build (and cache) the source-bucketed ELL layout for this
-    adjacency, or None when one window covers it — the driver-facing
-    alias of `repro.kernels.ell_relax.sweep_layout`.
+               max_window: Optional[int] = None) -> Optional[Layout]:
+    """The layout the sweep over this adjacency takes, built once per
+    graph on the host.
+
+    - The XLA form (the default on every platform): the in-degree
+      width classes (`DegreeBuckets`), so each sweep gathers only the
+      slots real in-edges use — on a road graph the arcs themselves,
+      not every row padded to the widest — bit-identically.
+    - The Pallas kernel (``REPRO_ELL_RELAX=kernel``): the
+      source-bucketed layout (`BucketedEll`, cached) past the
+      single-window VMEM budget, or None when one window covers the
+      graph.
 
     Callers that relax the same graph repeatedly under jit (engine
-    policies, `plant_batch`) build this once *eagerly* — the adjacency
-    is a tracer inside their jitted step functions, where bucketing is
-    impossible — and thread it through ``layout=``. Returns None as
-    well when the adjacency is itself traced, and when the sweep runs
-    its XLA form (the layout only feeds the Pallas kernel).
+    policies, `plant_batch`) build this *eagerly* — the adjacency is a
+    tracer inside their jitted step functions, where neither layout
+    can be built — and thread it through ``layout=``. Returns None
+    when the adjacency is itself traced.
     """
     if not resolve_use_kernel(None):
-        return None
+        return degree_buckets(ell_src, ell_w)
     return sweep_layout(ell_src, ell_w, max_window=max_window)
 
 
@@ -145,7 +166,7 @@ def batched_sssp_maxrank(
     check_every: Optional[int] = None,
     use_kernel: Optional[bool] = None,
     frontier_gating: Optional[bool] = None,
-    layout: Optional[BucketedEll] = None,
+    layout: Optional[Layout] = None,
 ) -> RelaxState:
     """Relax a batch of trees to fixpoint.
 
@@ -171,13 +192,16 @@ def batched_sssp_maxrank(
         on the dense-XLA path masking cannot reduce the gather cost
         and would only add per-sweep mask work. Either setting
         reaches the identical fixpoint (monotone lattice).
-      layout: optional precomputed `BucketedEll` (see `ell_layout`)
-        selecting the source-windowed kernel for adjacencies past the
-        single-window VMEM budget. When omitted and the adjacency is
-        concrete, the backend resolver builds + caches one on demand;
-        when the adjacency is traced (this function called under an
-        outer jit) the sweep falls back to the jnp reference with a
-        one-time warning — thread a layout in to keep the kernel.
+      layout: optional precomputed layout (see `ell_layout`). A
+        `DegreeBuckets` makes the XLA form gather through the graph's
+        in-degree width classes (the kernel ignores it). A
+        `BucketedEll` selects the source-windowed kernel for
+        adjacencies past the single-window VMEM budget; when it is
+        omitted and the adjacency is concrete, the backend resolver
+        builds + caches one on demand; when the adjacency is traced
+        (this function called under an outer jit) the sweep falls back
+        to the jnp reference with a one-time warning — thread a layout
+        in to keep the kernel.
 
     Returns:
       RelaxState with fixpoint ``dist``/``mrank``.
@@ -191,21 +215,47 @@ def batched_sssp_maxrank(
     # bucketed layout is available (given or buildable), and only fall
     # back to the reference — where gating + striding would only add
     # work — when the adjacency is traced with no layout threaded in
-    kern, layout = resolve_sweep_backend(ell_src, ell_w,
-                                         use_kernel=use_kernel,
-                                         layout=layout)
+    buckets = layout if isinstance(layout, DegreeBuckets) else None
+    kern, layout = resolve_sweep_backend(
+        ell_src, ell_w, use_kernel=use_kernel,
+        layout=None if buckets is not None else layout)
+    if kern:
+        buckets = None                 # the kernel reads the dense ELL
     gated = kern if frontier_gating is None else bool(frontier_gating)
     stride = ((DEFAULT_CHECK_EVERY if kern else 1)
               if check_every is None else check_every)
     stride = max(1, min(stride, cap))
-    dist0, mrank0 = _init(n, roots, rank)
+
+    # the loop runs in the layout's vertex order: rank and roots move
+    # there once, the planes move back once after the loop
+    if buckets is not None:
+        perm, inv = buckets.perm, buckets.inv
+        rank_s, roots_s = rank[perm], inv[roots]
+
+        def sweep(dist, mrank, prop, alive):
+            del alive                  # the XLA form cannot skip trees
+            return sweep_degree(dist, mrank, prop, mrank, buckets,
+                                rank_s)
+
+        def block_s(dist):
+            return block_fn(dist[:, inv], roots)[:, perm]
+    else:
+        rank_s, roots_s = rank, roots
+
+        def sweep(dist, mrank, prop, alive):
+            return ell_sweep(dist, mrank, prop, alive, ell_src, ell_w,
+                             rank, use_kernel=kern, layout=layout)
+
+        def block_s(dist):
+            return block_fn(dist, roots)
+    dist0, mrank0 = _init(n, roots_s, rank_s)
 
     def blocked_of(dist):
         if block_fn is None:
             return jnp.zeros(dist.shape, dtype=bool)
-        blk = block_fn(dist, roots)
+        blk = block_s(dist)
         # the root of each tree never blocks its own propagation
-        return blk.at[jnp.arange(B), roots].set(False)
+        return blk.at[jnp.arange(B), roots_s].set(False)
 
     has_block = block_fn is not None
     carry_blocked = has_block and gated
@@ -231,8 +281,7 @@ def batched_sssp_maxrank(
             prop = (jnp.where(blocked_of(dist), jnp.inf, dist)
                     if has_block else dist)
             alive = jnp.ones((B,), dtype=bool)
-        nd, nm = ell_sweep(dist, mrank, prop, alive, ell_src, ell_w,
-                           rank, use_kernel=kern, layout=layout)
+        nd, nm = sweep(dist, mrank, prop, alive)
         new_frontier = (nd < dist) | (nm != mrank)
         if carry_blocked:
             return (nd, nm, blocked, new_frontier), None
@@ -255,6 +304,8 @@ def batched_sssp_maxrank(
               if carry_blocked else (dist0, mrank0, frontier0))
     state, sweeps = jax.lax.while_loop(cond, body, (state0, jnp.int32(0)))
     dist, mrank = state[0], state[1]
+    if buckets is not None:
+        dist, mrank = dist[:, inv], mrank[:, inv]
     explored = jnp.sum(jnp.isfinite(dist), axis=-1).astype(jnp.int32)
     return RelaxState(dist=dist, mrank=mrank, sweeps=sweeps,
                       explored=explored)
@@ -265,7 +316,7 @@ def batched_sssp(ell_src: Array, ell_w: Array, roots: Array,
                  check_every: Optional[int] = None,
                  use_kernel: Optional[bool] = None,
                  frontier_gating: Optional[bool] = None,
-                 layout: Optional[BucketedEll] = None) -> Array:
+                 layout: Optional[Layout] = None) -> Array:
     """Plain batched SSSP distances (no rank tracking): f32 [B, n].
 
     Runs through the same fused/gated engine with a constant-zero rank

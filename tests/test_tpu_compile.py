@@ -6,7 +6,8 @@ would refuse (a kernel Mosaic cannot lower, a program that does not
 fit HBM) without running anything. These tests compile the programs
 the chip path runs, at deployment widths:
 
-- (a) the relaxation sweep (`plant_batch`, XLA form) at the one-chip
+- (a) the relaxation sweep (`plant_batch`, XLA form, over the dense
+  ELL and through in-degree width classes) at the one-chip
   smoke's n and road degree, and the label-table insert behind it;
 - (b) the jitted store and directed queries and the QLSN answer fn at
   L = 2088 (the default cap at NY road scale, n = 264,346) and
@@ -104,6 +105,31 @@ def test_plant_sweep_compiles_for_v5e(one_chip, on_tpu, batch):
         _spec((batch,), jnp.bool_, one_chip)).compile()
     _fits(compiled)
     # the chip path is the XLA form: no Pallas custom call in it
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_bucketed_plant_sweep_compiles_for_v5e(one_chip, on_tpu):
+    """The XLA sweep through in-degree width classes — the layout the
+    engine policies thread into `plant_batch` — at the smoke's n, with
+    a road's in-degrees 1..4 in rows of ``ROAD_DEG``."""
+    from repro.core.plant import plant_batch
+    from repro.kernels.ell_relax import degree_buckets
+    n, batch = SMOKE_N, 256
+    rng = np.random.default_rng(0)
+    deg = rng.integers(1, 5, n)
+    ell_src = rng.integers(0, n, (n, ROAD_DEG)).astype(np.int32)
+    ell_w = np.where(np.arange(ROAD_DEG)[None, :] < deg[:, None], 1.0,
+                     np.inf).astype(np.float32)
+    layout = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                          degree_buckets(ell_src, ell_w))
+    assert [wd for _, _, wd in layout.classes] == [1, 2, 3, 4]
+    compiled = plant_batch.lower(
+        _spec((n, ROAD_DEG), jnp.int32, one_chip),
+        _spec((n, ROAD_DEG), jnp.float32, one_chip),
+        _spec((n,), jnp.int32, one_chip),
+        _spec((batch,), jnp.int32, one_chip),
+        _spec((batch,), jnp.bool_, one_chip), layout=layout).compile()
+    _fits(compiled)
     assert "tpu_custom_call" not in compiled.as_text()
 
 
